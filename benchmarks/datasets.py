@@ -69,7 +69,7 @@ def aps_ptycho(
     return out
 
 
-def _gaussian_random_field(shape, slope: float, seed: int) -> np.ndarray:
+def gaussian_random_field(shape, slope: float, seed: int) -> np.ndarray:
     """FFT-synthesized field with power-law spectrum k^-slope."""
     rng = np.random.default_rng(seed)
     white = rng.standard_normal(shape)
@@ -102,7 +102,7 @@ DOMAIN_FIELDS = {
 
 def domain_field(name: str, seed: int = 3) -> np.ndarray:
     shape, slope, post = DOMAIN_FIELDS[name]
-    x = _gaussian_random_field(shape, slope, seed + hash(name) % 1000)
+    x = gaussian_random_field(shape, slope, seed + hash(name) % 1000)
     if post == "exp":
         x = np.exp(1.5 * x).astype(np.float32)
     elif post == "relu":

@@ -41,6 +41,9 @@ def main() -> None:
     )
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from . import (
         bench_aps,
         bench_chunked,

@@ -275,10 +275,12 @@ TRIVIAL_BITS = 0.05
 
 
 def _trial_bits(comp, sample: np.ndarray, eff: CompressionConfig) -> float:
+    """Coded bits/element of a trial compress; a candidate that cannot honour
+    the mode or bound (its contract says ``ValueError``) scores infinite."""
     try:
         with tel.suppress_decisions():  # runoff trials are not real outputs
             return 8.0 * len(comp.compress(sample, eff).blob) / max(1, sample.size)
-    except Exception:
+    except ValueError:
         return float("inf")
 
 

@@ -217,11 +217,10 @@ class FastModeCompressor:
             return None
         if self.device != "force" and xb.size < _DEVICE_MIN_SIZE:
             return None
-        try:
-            from ..kernels.fastmode import ops as fops
-        except Exception:  # jax/pallas unavailable -> host route
-            return None
-        if self.device != "force" and not fops.device_default():
+        from ..kernels import routing
+        from ..kernels.fastmode import ops as fops
+
+        if self.device != "force" and not routing.on_tpu():
             return None
         with tel.span("device_transfer", bytes=xb.nbytes):
             means32, dev32 = fops.block_stats(xb.astype(np.float32, copy=False))

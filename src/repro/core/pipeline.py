@@ -579,9 +579,9 @@ def sz3_interp(kind: str = "cubic", **kw) -> SZ3Compressor:
     )
 
 
-def sz3_lorenzo(order: int = 1, **kw) -> SZ3Compressor:
+def sz3_lorenzo(order: int = 1, device: str = "auto", **kw) -> SZ3Compressor:
     return SZ3Compressor(
-        predictor=pred_mod.LorenzoPredictor(order=order),
+        predictor=pred_mod.LorenzoPredictor(order=order, device=device),
         quantizer=quant_mod.LinearScaleQuantizer(),
         encoder=enc_mod.HuffmanEncoder(),
         lossless=ll_mod.Zstd(),

@@ -429,14 +429,13 @@ class LorenzoPredictor(Predictor):
             or data.size < self._DEVICE_MIN_SIZE
         ):
             return False
-        try:
-            from ..kernels.lorenzo import ops as lops
-        except Exception:  # jax/pallas unavailable -> numpy route
-            return False
+        from ..kernels import routing
+        from ..kernels.lorenzo import ops as lops
+
         absmax = float(np.abs(data).max())
         if not np.isfinite(absmax) or absmax / (2.0 * eb) >= lops.PIPELINE_SAFE:
             return False
-        return True if self.device == "force" else lops.device_default()
+        return self.device == "force" or routing.on_tpu()
 
     def _compress_device(self, data, quantizer):
         from ..kernels.lorenzo import ops as lops
@@ -470,11 +469,9 @@ class LorenzoPredictor(Predictor):
             return False
         if np.dtype(dtype) != np.float32:
             return False
-        try:
-            from ..kernels.lorenzo import ops as lops
-        except Exception:
-            return False
-        return True if self.device == "force" else lops.device_default()
+        from ..kernels import routing
+
+        return self.device == "force" or routing.on_tpu()
 
     # -- the two directions --------------------------------------------------
     def compress(self, data, quantizer, conf):
@@ -561,11 +558,7 @@ class LorenzoSequentialPredictor(Predictor):
         """mode: 'compress_linear' | 'compress_aligned' | 'decompress'."""
         import jax
         import jax.numpy as jnp
-
-        try:  # moved across jax versions (top-level alias added post-0.4)
-            from jax import enable_x64
-        except ImportError:
-            from jax.experimental import enable_x64
+        from jax import enable_x64
 
         subsets = self._stencil(shape)
         L = max(off for off, _, _ in subsets) + 1

@@ -185,7 +185,7 @@ class QualityCompressor:
         try:
             blob = comp.compress(sample, eff).blob
             return _finite_mse(sample, pl_mod.decompress(blob))
-        except Exception:
+        except ValueError:  # a bound the pipeline cannot honour
             return float("inf")  # treated as "too lossy": bisection tightens
 
     def _eb_for_mse(
@@ -248,7 +248,7 @@ class QualityCompressor:
             for est_fn in est_fns:
                 try:
                     best = min(best, float(est_fn(sample, eb, eff)))
-                except Exception:
+                except ValueError:  # a mode this estimator cannot price
                     pass
             return best
 

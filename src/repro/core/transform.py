@@ -231,11 +231,9 @@ class TransformCompressor:
             return False
         if x.dtype != np.float32 or x.size < self._DEVICE_MIN_SIZE:
             return False
-        try:
-            from ..kernels.transform import ops as tops
-        except Exception:  # jax/pallas unavailable -> host route
-            return False
-        return True if self.device == "force" else tops.device_default()
+        from ..kernels import routing
+
+        return self.device == "force" or routing.on_tpu()
 
     # -- compress ------------------------------------------------------------
     def compress(
@@ -397,14 +395,11 @@ class TransformCompressor:
         return out.astype(dtype).reshape(shape)
 
 
-def _jax_backend() -> Optional[str]:
-    """The active jax backend name, or None when jax is unavailable."""
-    try:
-        import jax
+def _jax_backend() -> str:
+    """The active jax backend name."""
+    import jax
 
-        return str(jax.default_backend())
-    except Exception:
-        return None
+    return str(jax.default_backend())
 
 
 def _decode_device_ok(pshape: Tuple[int, ...]) -> bool:
@@ -412,13 +407,9 @@ def _decode_device_ok(pshape: Tuple[int, ...]) -> bool:
     whose compress-time verification ran the same backend's kernel
     arithmetic (the caller checks ``device_backend``); every other blob
     takes the host float64 inverse, which compress always verifies."""
-    if len(pshape) not in (1, 2):
-        return False
-    try:
-        from ..kernels.transform import ops as tops
-    except Exception:
-        return False
-    return tops.device_default()
+    from ..kernels import routing
+
+    return len(pshape) in (1, 2) and routing.on_tpu()
 
 
 # ---------------------------------------------------------------------------

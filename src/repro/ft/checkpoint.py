@@ -110,7 +110,9 @@ def encode_leaf(
 ) -> Tuple[bytes, Dict[str, Any]]:
     meta: Dict[str, Any] = {
         "shape": list(arr.shape),
-        "dtype": arr.dtype.str,
+        # extension dtypes (ml_dtypes' bfloat16) have no array-protocol
+        # string of their own (``.str`` is '<V2'): record them by name
+        "dtype": arr.dtype.name if arr.dtype.kind == "V" else arr.dtype.str,
         "mode": pol.mode,
     }
     if (
@@ -245,7 +247,7 @@ class CheckpointManager:
             shutil.rmtree(tmp)
         tmp.mkdir(parents=True)
         leaves = {}
-        flat, treedef = jax.tree_util.tree_flatten_with_path(host_state)
+        flat, _ = jax.tree_util.tree_flatten_with_path(host_state)
         total_in = total_out = 0
         for path, leaf in flat:
             pstr = _path_str(path)
@@ -278,9 +280,6 @@ class CheckpointManager:
         manifest = {
             "step": step,
             "leaves": leaves,
-            "treedef": jax.tree_util.tree_structure(host_state).serialize_using_proto().hex()
-            if hasattr(jax.tree_util.tree_structure(host_state), "serialize_using_proto")
-            else None,
             "bytes_in": total_in,
             "bytes_out": total_out,
             "ratio": total_in / max(1, total_out),

@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
-
 
 def _encode_kernel(v_ref, w_ref):
     v = v_ref[...]  # (bt, 32) uint32
@@ -33,7 +31,7 @@ def _decode_kernel(w_ref, v_ref):
     v_ref[...] = (bits << p).sum(axis=0, dtype=jnp.uint32)  # (bt, 32)
 
 
-def encode(v, *, bt=512, interpret=True):
+def encode(v, *, bt=512, interpret):
     R = v.shape[0]
     return pl.pallas_call(
         _encode_kernel,
@@ -41,12 +39,12 @@ def encode(v, *, bt=512, interpret=True):
         grid=(R // bt,),
         in_specs=[pl.BlockSpec((bt, 32), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((32, bt), lambda i: (0, i)),
-        compiler_params=tpu_compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(v)
 
 
-def decode(w, *, bt=512, interpret=True):
+def decode(w, *, bt=512, interpret):
     R = w.shape[1]
     return pl.pallas_call(
         _decode_kernel,
@@ -54,6 +52,6 @@ def decode(w, *, bt=512, interpret=True):
         grid=(R // bt,),
         in_specs=[pl.BlockSpec((32, bt), lambda i: (0, i))],
         out_specs=pl.BlockSpec((bt, 32), lambda i: (i, 0)),
-        compiler_params=tpu_compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(w)

@@ -18,10 +18,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
 
-_PAR = tpu_compiler_params(("parallel",))
+_PAR = pltpu.CompilerParams(dimension_semantics=("parallel",))
 
 
 def _kernel(x_ref, mean_ref, dev_ref, *, bs):
@@ -32,7 +32,7 @@ def _kernel(x_ref, mean_ref, dev_ref, *, bs):
     dev_ref[...] = jnp.broadcast_to(dev, dev_ref.shape)
 
 
-def block_stats(x, *, bm=8, interpret=True):
+def block_stats(x, *, bm=8, interpret):
     """(nb, bs) float32, nb % bm == 0 -> (means, devs), each (nb, 128)."""
     nb, bs = x.shape
     kern = functools.partial(_kernel, bs=bs)

@@ -1,33 +1,22 @@
 """Jit'd public wrappers around the fast-tier classify+reduce kernel.
 
 Handles row padding to tile multiples (pad rows are all-zero blocks whose
-stats are cropped before the coder sees them), backend selection
-(interpret=True on CPU, compiled on TPU), and the host-array boundary for
-core/fastmode.py's device path.
+stats are cropped before the coder sees them) and the host-array boundary
+for core/fastmode.py's device path.  ``interpret=None`` resolves by backend
+in :mod:`repro.kernels.routing`.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import routing
 from . import kernel as _k
 from . import ref as _ref
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def device_default() -> bool:
-    """Route the fast coder's stats stage through Pallas by default?
-
-    True on real TPUs only — interpret-mode Pallas on CPU is far slower than
-    the numpy host path (same policy as kernels/lorenzo and transform)."""
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
@@ -37,10 +26,10 @@ def _stats_padded(x: jnp.ndarray, *, bm: int, interpret: bool):
 
 
 def block_stats(
-    x: np.ndarray, *, interpret: bool = None
+    x: np.ndarray, *, interpret: Optional[bool] = None
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Per-block (mean, max |x - mean|) for a host (nb, bs) float32 array."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = routing.interpret_mode(interpret)
     x = np.asarray(x, np.float32)
     nb = x.shape[0]
     bm = 256 if nb >= 256 else 8

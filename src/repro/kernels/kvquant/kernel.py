@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import tpu_compiler_params
 
 SCALE_FLOOR = 1e-8
 
@@ -53,7 +52,7 @@ def _quant_kernel(x_ref, scale_ref, q_ref):
     q_ref[...] = q.astype(jnp.int8)
 
 
-def absmax(x, *, bm=256, interpret=True):
+def absmax(x, *, bm=256, interpret):
     T, C = x.shape
     return pl.pallas_call(
         _absmax_kernel,
@@ -62,12 +61,12 @@ def absmax(x, *, bm=256, interpret=True):
         in_specs=[pl.BlockSpec((bm, C), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, C), lambda i: (0, 0)),
         scratch_shapes=[pltpu.VMEM((1, C), jnp.float32)],
-        compiler_params=tpu_compiler_params(("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x)
 
 
-def quantize_with_scale(x, scale, *, bm=256, bn=128, interpret=True):
+def quantize_with_scale(x, scale, *, bm=256, bn=128, interpret):
     T, C = x.shape
     return pl.pallas_call(
         _quant_kernel,
@@ -78,7 +77,9 @@ def quantize_with_scale(x, scale, *, bm=256, bn=128, interpret=True):
             pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        compiler_params=tpu_compiler_params(("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")
+        ),
         interpret=interpret,
     )(x, scale)
 
@@ -103,7 +104,7 @@ def _dequant_matmul_kernel(a_ref, q_ref, scale_ref, o_ref, acc_ref):
         o_ref[...] = acc_ref[...] * scale_ref[...]  # per-column epilogue
 
 
-def dequant_matmul(a, q, scale, *, bm=128, bn=128, bk=128, interpret=True):
+def dequant_matmul(a, q, scale, *, bm=128, bn=128, bk=128, interpret):
     M, K = a.shape
     K2, N = q.shape
     assert K == K2 and scale.shape == (1, N)
@@ -119,6 +120,8 @@ def dequant_matmul(a, q, scale, *, bm=128, bn=128, bk=128, interpret=True):
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
     )(a, q, scale)

@@ -1,20 +1,18 @@
-"""Jit'd wrappers for KV quantization kernels (padding + backend select)."""
+"""Jit'd wrappers for KV quantization kernels (padding; ``interpret=None``
+resolves by backend in :mod:`repro.kernels.routing`)."""
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from .. import routing
 from . import kernel as _k
 from . import ref as _ref
 
 SCALE_FLOOR = _k.SCALE_FLOOR
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x, m0, m1):
@@ -25,8 +23,11 @@ def _pad_to(x, m0, m1):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def kv_quantize(x: jnp.ndarray, *, interpret: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def kv_quantize(
+    x: jnp.ndarray, *, interpret: Optional[bool] = None
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(T, C) -> (int8 codes (T, C), per-channel scale (C,))."""
+    interpret = routing.interpret_mode(interpret)
     T, C = x.shape
     xp = _pad_to(x.astype(jnp.float32), 256, 128)
     amax = _k.absmax(xp, interpret=interpret)  # (1, Cp)
@@ -37,7 +38,8 @@ def kv_quantize(x: jnp.ndarray, *, interpret: bool = True) -> Tuple[jnp.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def kv_dequant_matmul(
-    a: jnp.ndarray, q: jnp.ndarray, scale: jnp.ndarray, *, interpret: bool = True
+    a: jnp.ndarray, q: jnp.ndarray, scale: jnp.ndarray, *,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """a (M, K) @ dequant(q (K, N), scale (N,)) -> (M, N) f32."""
     M, K = a.shape
@@ -45,6 +47,7 @@ def kv_dequant_matmul(
     ap = _pad_to(a.astype(jnp.float32), 128, 128)
     qp = _pad_to(q, 128, 128)
     sp = jnp.pad(scale, (0, (-N) % 128)).reshape(1, -1)
+    interpret = routing.interpret_mode(interpret)
     out = _k.dequant_matmul(ap, qp, sp, interpret=interpret)
     return out[:M, :N]
 

@@ -1,7 +1,6 @@
 from .ops import (
     lorenzo_decode,
     lorenzo_encode,
-    lorenzo_roundtrip_check,
     ref_decode,
     ref_encode,
 )
@@ -9,7 +8,6 @@ from .ops import (
 __all__ = [
     "lorenzo_encode",
     "lorenzo_decode",
-    "lorenzo_roundtrip_check",
     "ref_encode",
     "ref_decode",
 ]

@@ -1,23 +1,22 @@
 """Jit'd public wrappers around the fused Lorenzo kernels.
 
-Handles padding to tile multiples, the int32 fast-path guard
-(|x| / (2*eb) must stay below 2^30; otherwise callers use the core numpy
-int64 path), backend selection (interpret=True on CPU, compiled on TPU), and
-unpredictable-point bookkeeping for the device compression path.
+Handles padding to tile multiples, the fast-path guard (``PIPELINE_SAFE``;
+beyond it callers use the core numpy int64 path), and the host array
+boundary for the device compression path.
+``interpret=None`` resolves by backend in :mod:`repro.kernels.routing`.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import routing
 from . import kernel as _k
 from . import ref as _ref
-
-INT32_SAFE = float(1 << 30)
 
 #: guard for the BOUND-EXACT pipeline fast path (predictors.LorenzoPredictor):
 #: beyond int32 range safety, prequantized magnitudes must stay small enough
@@ -26,23 +25,9 @@ INT32_SAFE = float(1 << 30)
 PIPELINE_SAFE = float(1 << 22)
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def device_default() -> bool:
-    """Should the main pipeline route through the fused kernels by default?
-
-    True on real TPUs (compiled Pallas).  On CPU the kernels only run in
-    interpret mode — orders of magnitude slower than the numpy path — so the
-    pipeline keeps numpy unless a caller forces the kernel path (tests do,
-    on small arrays).
-    """
-    return jax.default_backend() == "tpu"
-
-
 def encode_pipeline(
-    x: np.ndarray, *, eb: float, radius: int = 32768, interpret: bool = None
+    x: np.ndarray, *, eb: float, radius: int = 32768,
+    interpret: Optional[bool] = None,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Fused prequant+Lorenzo encode for the REAL pipeline (host arrays).
 
@@ -51,7 +36,6 @@ def encode_pipeline(
     are responsible for the PIPELINE_SAFE guard and for verifying/patching
     reconstruction against the error bound (predictors.LorenzoPredictor).
     """
-    interpret = _interpret_default() if interpret is None else interpret
     x2d = jnp.asarray(x if x.ndim == 2 else x.reshape(1, -1), jnp.float32)
     mode = "2d" if x.ndim == 2 else "1d"
     codes, draw = lorenzo_encode(
@@ -72,6 +56,12 @@ def _pad2d(x: jnp.ndarray, bm: int, bn: int) -> Tuple[jnp.ndarray, Tuple[int, in
     return x, (R, C)
 
 
+def _tiles(shape: Tuple[int, int]) -> Tuple[int, int]:
+    bm = 256 if shape[0] >= 256 else max(8, 8 * (shape[0] // 8) or 8)
+    bn = 512 if shape[1] >= 512 else 128
+    return bm, bn
+
+
 @functools.partial(jax.jit, static_argnames=("eb", "radius", "mode", "interpret"))
 def lorenzo_encode(
     x: jnp.ndarray,
@@ -79,64 +69,44 @@ def lorenzo_encode(
     eb: float,
     radius: int = 32768,
     mode: str = "2d",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fused prequant+Lorenzo encode. Returns (codes, raw_diffs), both int32,
     cropped to the input shape.  mode: "1d" (row-independent) | "2d"."""
     assert x.ndim == 2, "reshape to 2-D before calling (rows, fastest-axis)"
-    bm = 256 if x.shape[0] >= 256 else max(8, 8 * (x.shape[0] // 8) or 8)
-    if mode == "1d":
-        bn = 512 if x.shape[1] >= 512 else 128
-        xp, (R, C) = _pad2d(x, bm, bn)
-        codes, draw = _k.encode_1d(xp, eb, radius, bm=bm, bn=bn, interpret=interpret)
-    else:
-        xp, (R, C) = _pad2d(x, bm, 128)
-        codes, draw = _k.encode_2d(xp, eb, radius, bm=bm, interpret=interpret)
+    bm, bn = _tiles(x.shape)
+    xp, (R, C) = _pad2d(x, bm, bn)
+    interpret = routing.interpret_mode(interpret)
+    codes, draw = _k.encode(xp, eb, radius, mode=mode, bm=bm, bn=bn, interpret=interpret)
     return codes[:R, :C], draw[:R, :C]
 
 
 @functools.partial(jax.jit, static_argnames=("eb", "mode", "interpret"))
 def lorenzo_decode(
-    d: jnp.ndarray, *, eb: float, mode: str = "2d", interpret: bool = True
+    d: jnp.ndarray, *, eb: float, mode: str = "2d", interpret: Optional[bool] = None
 ) -> jnp.ndarray:
-    """Inverse (cumsum) + dequant.  ``d`` must contain the raw diffs with
+    """Inverse (prefix sums) + dequant.  ``d`` must contain the raw diffs with
     unpredictable positions already substituted."""
     assert d.ndim == 2
-    bm = 256 if d.shape[0] >= 256 else max(8, 8 * (d.shape[0] // 8) or 8)
-    if mode == "1d":
-        bn = 512 if d.shape[1] >= 512 else 128
-        dp, (R, C) = _pad2d(d, bm, bn)
-        out = _k.decode_1d(dp, eb, bm=bm, bn=bn, interpret=interpret)
-    else:
-        dp, (R, C) = _pad2d(d, bm, 128)
-        out = _k.decode_2d(dp, eb, bm=bm, interpret=interpret)
+    bm, bn = _tiles(d.shape)
+    dp, (R, C) = _pad2d(d, bm, bn)
+    interpret = routing.interpret_mode(interpret)
+    out = _k.decode(dp, eb, mode=mode, bm=bm, bn=bn, interpret=interpret)
     return out[:R, :C]
 
 
 def decode_pipeline(
-    d: np.ndarray, *, eb: float, interpret: bool = None
+    d: np.ndarray, *, eb: float, interpret: Optional[bool] = None
 ) -> np.ndarray:
-    """Fused cumsum+dequant decode for the REAL pipeline (host arrays).
+    """Fused prefix-sum+dequant decode for the REAL pipeline (host arrays).
 
     Inverse of :func:`encode_pipeline`: 1-D or 2-D int32 raw diffs (with
     unpredictable positions already substituted) -> float32 reconstruction.
     """
-    interpret = _interpret_default() if interpret is None else interpret
     d2 = jnp.asarray(d if d.ndim == 2 else d.reshape(1, -1), jnp.int32)
     mode = "2d" if d.ndim == 2 else "1d"
     out = lorenzo_decode(d2, eb=float(eb), mode=mode, interpret=interpret)
     return np.asarray(out).reshape(d.shape)
-
-
-def lorenzo_roundtrip_check(x: np.ndarray, eb: float) -> dict:
-    """Convenience: encode+decode through the kernel path, report bound/ratio
-    stats (used by tests and the device checkpoint path)."""
-    x = jnp.asarray(x, jnp.float32)
-    assert float(jnp.max(jnp.abs(x))) / (2 * eb) < INT32_SAFE, "int32 fast path"
-    codes, draw = lorenzo_encode(x, eb=eb, interpret=_interpret_default())
-    xhat = lorenzo_decode(draw, eb=eb, interpret=_interpret_default())
-    err = float(jnp.max(jnp.abs(xhat - x)))
-    return {"max_err": err, "codes": np.asarray(codes), "draw": np.asarray(draw)}
 
 
 def ref_encode(x, eb, radius=32768, mode="2d"):

@@ -1,6 +1,5 @@
 from .ops import (
     AMP_1AXIS,
-    device_default,
     fwd_pipeline,
     inv_pipeline,
     ref_fwd,
@@ -11,7 +10,6 @@ from .ops import (
 
 __all__ = [
     "AMP_1AXIS",
-    "device_default",
     "fwd_pipeline",
     "inv_pipeline",
     "ref_fwd",

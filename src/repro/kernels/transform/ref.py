@@ -14,6 +14,7 @@ analysis (the L_inf amplification of ``M^T``) transfers only then.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ...core.transform import AMP_1AXIS, MAT  # noqa: F401  (shared basis)
@@ -36,7 +37,10 @@ def _apply(x: jnp.ndarray, m: jnp.ndarray, axes) -> jnp.ndarray:
     out = x
     for ax in axes:
         b = _blocked(out, ax)
-        out = _unblocked(b @ m.T.astype(out.dtype), ax, out.shape)
+        # full float32 passes: a TPU's default matmul precision rounds the
+        # operands to bfloat16, which no oracle may do
+        prod = jnp.matmul(b, m.T.astype(out.dtype), precision=jax.lax.Precision.HIGHEST)
+        out = _unblocked(prod, ax, out.shape)
     return out
 
 

@@ -1,6 +1,10 @@
 import os
 
+# a CPU-only tool: 512 simulated host devices, and never the accelerator —
+# a chip belongs to one process, and this one (or a per-cell child, which
+# inherits the environment) must not take it from the program that runs
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run (deliverable e): lower + compile every assigned
 (architecture x input-shape) cell on the production meshes, record

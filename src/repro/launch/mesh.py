@@ -17,4 +17,4 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 def make_debug_mesh(shape=(1, 1), axes=("data", "model")):
     """Small mesh over however many (possibly fake) local devices exist."""
-    return compat.make_mesh(shape, axes)
+    return compat.make_mesh(shape, axes, auto_axis_types=True)

@@ -4,7 +4,9 @@ Batched greedy decode with the (optionally int8-quantized) KV cache —
 the paper's quantizer on the serving path.  ``--offload-kv chunked``
 additionally streams the finished cache through the chunked compression
 engine (repro.core.chunking) frame by frame — the bounded-memory offload
-path for evicting sequences to host/disk under heavy traffic.
+path for evicting sequences to host/disk under heavy traffic.  The reduced
+smoke config runs unless ``--no-smoke``; ``main(argv)`` returns the decoded
+token ids and the offload byte counts.
 """
 from __future__ import annotations
 
@@ -20,12 +22,17 @@ from repro import models
 from repro.core import telemetry
 from repro.parallel import ParallelPlan
 
+from .compile_cache import use_compile_cache
+
 log = telemetry.get_logger("serve")
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b", choices=configs.ARCHS)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction, default=True,
+                    help="use the reduced config; --no-smoke runs the "
+                         "published widths")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--kv", default="bf16", choices=["bf16", "int8"])
@@ -86,9 +93,10 @@ def main():
         "offload-frame latency percentiles, verify-failure counters) and the "
         "per-stage offload trace summary before exiting",
     )
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    use_compile_cache()
 
-    cfg = configs.get_smoke(args.arch)
+    cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     plan = ParallelPlan(kv_cache_dtype=args.kv)
     params = models.init_params(jax.random.PRNGKey(0), cfg, plan)
     enc_frames = None
@@ -122,7 +130,7 @@ def main():
         tok_per_s=args.tokens * args.batch / dt,
         sample=str(seqs[0][:12].tolist()),
     )
-    tr = None
+    tr = offload = None
     if args.offload_kv in ("chunked", "auto", "hybrid", "quality", "fast"):
         candidates = None
         if args.offload_kv == "auto":
@@ -137,7 +145,7 @@ def main():
         )
         with scope as tr:
             if args.offload_async and args.offload_kv != "quality":
-                offload_cache_async(
+                offload = offload_cache_async(
                     cache,
                     eb=args.offload_eb,
                     workers=args.offload_workers,
@@ -146,7 +154,7 @@ def main():
                     executor=args.offload_executor,
                 )
             else:
-                offload_cache(
+                offload = offload_cache(
                     cache,
                     eb=args.offload_eb,
                     workers=args.offload_workers,
@@ -158,6 +166,7 @@ def main():
         print(telemetry.prometheus_text(), end="")
         if tr is not None:
             print(telemetry.trace_summary(tr))
+    return {"tokens": seqs, "offload": offload}
 
 
 class _NullScope:

@@ -1,9 +1,13 @@
-"""Training launcher: ``python -m repro.launch.train --arch <id> [--smoke]``.
+"""Training launcher: ``python -m repro.launch.train --arch <id> [--no-smoke]``.
 
-Selects any assigned architecture config, builds the per-cell parallel plan
-(single device on CPU; production mesh when devices allow), and runs the full
-production loop: sharded train step, microbatching, SZ3-compressed
-checkpoints, deterministic resumable data, heartbeat monitoring.
+Selects any assigned architecture config (the reduced smoke variant unless
+``--no-smoke``), builds the per-cell parallel plan (single device, or a mesh
+over the local devices with ``--mesh``), and runs the full production loop:
+sharded train step, microbatching, SZ3-compressed checkpoints, deterministic
+resumable data, heartbeat monitoring.  ``main(argv)`` returns the final
+state, the per-step losses and wall seconds (the first step's include its
+compilation) and the checkpoint manager, so a driver script can check them
+in the same process.
 """
 from __future__ import annotations
 
@@ -19,20 +23,30 @@ from repro.data import make_pipeline
 from repro.ft import CheckpointManager, HeartbeatMonitor
 from repro.optim import AdamWConfig
 from repro.parallel import ParallelPlan
-from repro.train.step import init_train_state, make_train_step
+from repro.train.step import (
+    init_train_state,
+    jit_train_step,
+    make_train_step,
+    state_shardings,
+)
+
+from .compile_cache import use_compile_cache
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b", choices=configs.ARCHS)
-    ap.add_argument("--smoke", action="store_true", default=True,
-                    help="use the reduced config (full configs need a pod)")
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction, default=True,
+                    help="use the reduced config; --no-smoke runs the "
+                         "published widths")
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--lr", type=float, default=3e-3)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_launch_train")
+    ap.add_argument("--ckpt-dir", default="/tmp/repro_launch_train",
+                    help="checkpoint directory; a run resumes from the "
+                         "newest checkpoint found there")
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--compress-moments", action="store_true")
     ap.add_argument("--mesh", default="",
@@ -47,10 +61,11 @@ def main():
     ap.add_argument("--compress-opt", default="", metavar="POLICY",
                     help="compressed optimizer moments with this jitmode "
                          "policy spec (implies --compress-moments)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
-    mesh = None
+    mesh, names = None, ()
     if args.mesh:
         from .mesh import make_debug_mesh
 
@@ -63,6 +78,7 @@ def main():
         grad_policy = f"int{grad_policy}"
     plan = ParallelPlan(
         mesh=mesh,
+        model_axis="model" if "model" in names else None,
         microbatches=args.microbatches,
         grad_policy=grad_policy,
     )
@@ -85,22 +101,34 @@ def main():
         start = int(extra.get("next_step", 0))
         print(f"resumed at step {start}")
 
-    step_fn = jax.jit(make_train_step(cfg, plan, opt, total_steps=args.steps),
-                      donate_argnums=0)
+    step_fn = make_train_step(cfg, plan, opt, total_steps=args.steps)
+    if mesh is None:
+        step_fn = jax.jit(step_fn, donate_argnums=0)
+    else:
+        # place the state on the mesh as the step's shardings say, so the
+        # first step does not start from arrays on one device
+        batch0 = {k: jnp.asarray(v) for k, v in pipe.batch_at(start).items()}
+        step_fn = jit_train_step(step_fn, state, cfg, plan, opt, batch0)
+        state = jax.device_put(state, state_shardings(state, cfg, plan, opt))
+    losses, seconds = [], []
     t0 = time.perf_counter()
     for k in range(start, args.steps):
         batch = {k2: jnp.asarray(v) for k2, v in pipe.batch_at(k).items()}
         state, m = step_fn(state, batch)
+        losses.append(float(m["loss"]))
         dt = time.perf_counter() - t0
         t0 = time.perf_counter()
+        seconds.append(dt)
         mon.beat("host0", dt)
         if k % 5 == 0 or k == args.steps - 1:
-            print(f"step {k:4d} loss={float(m['loss']):.4f} "
+            print(f"step {k:4d} loss={losses[-1]:.4f} "
                   f"({args.batch * args.seq / dt:,.0f} tok/s)")
         if (k + 1) % args.ckpt_every == 0:
             mgr.save(k + 1, state, extra={"next_step": k + 1})
     mgr.wait()
     print("done; checkpoints:", mgr.list_steps())
+    return {"state": state, "losses": losses, "step_seconds": seconds,
+            "ckpt": mgr, "cfg": cfg}
 
 
 if __name__ == "__main__":

@@ -1,78 +1,40 @@
-"""Version compatibility for the jax sharding surface.
+"""The jax sharding surface the repo calls, in one module.
 
-The repo is written against the modern names (``jax.shard_map`` with
-``axis_names``/``check_vma``, ``jax.make_mesh(..., axis_types=...)``,
-``jax.sharding.get_abstract_mesh``), but the pinned environment ships a jax
-where shard_map still lives in ``jax.experimental.shard_map`` with the
-``auto``/``check_rep`` spelling and meshes have no axis types.  Rather than
-sprinkle version probes through ``parallel/``, ``models/`` and ``train/``,
-every call site routes through this one module — which is also what lets
-``tests/test_distributed.py`` actually run on the pinned jax instead of
-skipping (the old ``requires_explicit_sharding`` probe keyed on the modern
-names existing and deselected the whole distributed lane).
+``parallel/``, ``models/`` and ``train/`` reach ``jax.shard_map``,
+``jax.make_mesh`` and the ambient abstract mesh only through here, so the
+spelling the repo relies on is written down once.
 
-``shard_map`` here speaks the modern argument names: ``axis_names`` is the
-set of mesh axes the region is MANUAL over; on old jax it is translated to
-``auto = mesh.axis_names - axis_names``.  Callers should prefer manual over
-ALL mesh axes — partial-manual regions (non-empty ``auto``) trip an XLA-CPU
-partitioner crash (``IsManualSubgroup`` check failure in the SPMD
-partitioner) on the pinned version, which is exactly why the compressed
-train-step region runs fully manual.
+``shard_map`` takes ``axis_names``: the set of mesh axes the region is
+MANUAL over.  Callers prefer manual over ALL mesh axes — the compressed
+train-step region runs fully manual, which keeps model compute purely local
+and avoids partial-manual partitioning (an XLA-CPU SPMD partitioner check
+failure, ``IsManualSubgroup``, in earlier releases).
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
-
-_HAS_MODERN_SHARD_MAP = hasattr(jax, "shard_map")
 
 
 def shard_map(f, mesh, *, axis_names, in_specs, out_specs, check_vma=False):
-    """Modern-style shard_map that also runs on the legacy API.
-
-    ``axis_names``: iterable of mesh axis names the body is manual over.
-    """
-    manual = frozenset(axis_names)
-    if _HAS_MODERN_SHARD_MAP:
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            axis_names=manual,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    auto = frozenset(mesh.axis_names) - manual
-    return _legacy(
+    """``jax.shard_map`` manual over ``axis_names``."""
+    return jax.shard_map(
         f,
         mesh=mesh,
+        axis_names=frozenset(axis_names),
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=check_vma,
-        auto=auto,
+        check_vma=check_vma,
     )
 
 
 def make_mesh(axis_shapes, axis_names, *, auto_axis_types: bool = False):
-    """``jax.make_mesh`` with the axis-type request dropped where the
-    installed jax predates mesh axis types (plain meshes behave as Auto
-    there, so the semantics match)."""
-    if auto_axis_types and hasattr(jax.sharding, "AxisType"):
+    """``jax.make_mesh``; ``auto_axis_types`` marks every axis ``Auto``."""
+    if auto_axis_types:
         types = (jax.sharding.AxisType.Auto,) * len(axis_names)
-        try:
-            return jax.make_mesh(axis_shapes, axis_names, axis_types=types)
-        except TypeError:
-            pass
+        return jax.make_mesh(axis_shapes, axis_names, axis_types=types)
     return jax.make_mesh(axis_shapes, axis_names)
 
 
-def get_abstract_mesh() -> Optional[object]:
-    """The ambient abstract mesh when the installed jax tracks one, else
-    None (legacy jax: nested shard_map takes the concrete mesh)."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        return None
-    return fn()
+def get_abstract_mesh():
+    """The ambient abstract mesh (empty outside a mesh context)."""
+    return jax.sharding.get_abstract_mesh()

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import asyncio
 import multiprocessing
+import os
 import threading
 import time
 import zlib
@@ -293,6 +294,21 @@ class DecodeStateCache:
 _WORKER_CACHE: Optional[DecodeStateCache] = None
 
 
+def _host_only_worker() -> None:
+    """Process-pool initializer: pin the worker to JAX's CPU backend.
+
+    The parent process holds the accelerator, and a chip belongs to one
+    process at a time: a worker that reached for it would fail or hang.
+    Importing this module initialises no backend, so the pin takes effect
+    before any jax use.  On the CPU backend every ``device="auto"`` route of
+    the engines resolves to the host path (``repro.kernels.routing``).
+    """
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _process_cache() -> DecodeStateCache:
     global _WORKER_CACHE
     if _WORKER_CACHE is None:
@@ -464,10 +480,12 @@ class OffloadService:
         if self._executor is None:
             if self.executor_kind == "process":
                 # spawn, not fork: the host process is multithreaded (asyncio
-                # + jax) and fork-with-threads can deadlock in the child
+                # + jax) and fork-with-threads can deadlock in the child;
+                # workers are host-only, the parent keeps the chip
                 self._executor = ProcessPoolExecutor(
                     max_workers=self.workers,
                     mp_context=multiprocessing.get_context("spawn"),
+                    initializer=_host_only_worker,
                 )
             else:
                 self._executor = ThreadPoolExecutor(

@@ -113,8 +113,9 @@ def _pstr(path) -> str:
 def init_train_state(key, cfg: ModelConfig, plan: ParallelPlan, opt_cfg: AdamWConfig):
     params = models.init_params(key, cfg, plan)
     state = {"params": params, "opt": init_state(params, opt_cfg)}
-    if plan.grad_compression() is not None:
-        state["feedback"] = gradc.init_feedback(params, plan.dp)
+    pol = plan.grad_compression()
+    if pol is not None:
+        state["feedback"] = gradc.init_feedback(params, plan.dp, pol.bs)
     return state
 
 
@@ -222,16 +223,29 @@ def make_train_step(
             sp["feedback"] = P(b)
             return sp
 
-        # the manual region can't run eagerly (closed_call under shard_map is
-        # jit-only on 0.4.x), so the factory's contract — a callable that
-        # just works — needs the jit here.  jit_train_step may wrap this
-        # again with explicit shardings; nested jit is inlined at trace time.
+        # the factory's contract is a callable that just works, eagerly
+        # too, so the manual region is jitted here.  jit_train_step may wrap
+        # this again with explicit shardings; nested jit is inlined at trace
+        # time.
         return jax.jit(train_step)
 
     def train_step(state, batch):
         return step_core(state, batch, inner_plan=plan)
 
     return train_step
+
+
+def _named(tree, mesh):
+    return jax.tree.map(
+        lambda s: jax.sharding.NamedSharding(mesh, s) if isinstance(s, P) else s,
+        tree,
+        is_leaf=lambda s: isinstance(s, P),
+    )
+
+
+def state_shardings(state, cfg: ModelConfig, plan: ParallelPlan, opt_cfg: AdamWConfig):
+    """NamedShardings of the train state on ``plan.mesh`` (for device_put)."""
+    return _named(state_specs(state, cfg, plan, opt_cfg), plan.mesh)
 
 
 def jit_train_step(
@@ -245,17 +259,11 @@ def jit_train_step(
     """AOT-jit with explicit in/out shardings (the dry-run entry point)."""
     if plan.mesh is None:
         return jax.jit(train_step)
-    sspecs = state_specs(state, cfg, plan, opt_cfg)
-    bspecs = batch_specs(batch_shapes, plan)
-    shard = lambda tree: jax.tree.map(
-        lambda s: jax.sharding.NamedSharding(plan.mesh, s) if isinstance(s, P) else s,
-        tree,
-        is_leaf=lambda s: isinstance(s, P),
-    )
+    sshard = state_shardings(state, cfg, plan, opt_cfg)
     metric_specs = {"grad_norm": P(), "loss": P()}
     return jax.jit(
         train_step,
-        in_shardings=(shard(sspecs), shard(bspecs)),
-        out_shardings=(shard(sspecs), shard(metric_specs)),
+        in_shardings=(sshard, _named(batch_specs(batch_shapes, plan), plan.mesh)),
+        out_shardings=(sshard, _named(metric_specs, plan.mesh)),
         donate_argnums=(0,),
     )

@@ -7,10 +7,9 @@ parity, compressed-gradient DP reduction (including >=20-step loss-trajectory
 parity against the uncompressed schedule), and elastic restore onto a
 different mesh (full-leaf and chunk-range paths).
 
-The mesh preamble goes through repro.parallel.compat, which bridges the
-explicit-sharding API gap between jax releases (jax.sharding.AxisType /
-get_abstract_mesh on new jax, jax.experimental.shard_map on 0.4.x) — these
-tests run on either, so there is no version skip.
+The mesh preamble goes through repro.parallel.compat, the one module that
+spells the sharding API the repo relies on (jax.make_mesh with Auto axis
+types, jax.shard_map).
 """
 import os
 import subprocess

@@ -1,5 +1,6 @@
 """Fault tolerance: compressed checkpoints (atomic, bounded-lossy), restore,
 resume-determinism, heartbeat policy, elastic replanning."""
+import dataclasses
 import json
 import os
 import shutil
@@ -35,8 +36,10 @@ def _state(seed=0):
     return cfg, opt, init_train_state(jax.random.PRNGKey(seed), cfg, PLAN, opt)
 
 
-def test_checkpoint_roundtrip_lossless_params(tmp_path):
-    cfg, opt, state = _state()
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_checkpoint_roundtrip_lossless_params(tmp_path, dtype):
+    cfg = dataclasses.replace(configs.get_smoke("qwen1.5-0.5b"), dtype=dtype)
+    state = init_train_state(jax.random.PRNGKey(0), cfg, PLAN, AdamWConfig())
     mgr = CheckpointManager(tmp_path, use_async=False)
     mgr.save(7, state)
     template = jax.tree.map(np.asarray, state)
@@ -44,6 +47,7 @@ def test_checkpoint_roundtrip_lossless_params(tmp_path):
     for a, b in zip(
         jax.tree.leaves(template["params"]), jax.tree.leaves(restored["params"])
     ):
+        assert a.dtype == b.dtype == np.dtype(dtype)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 

@@ -3,16 +3,6 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.compat import HAS_PALLAS_TPU
-
-# missing CompilerParams is NOT a skip: the compat shim passes None and the
-# interpret-mode path these tests use accepts that
-if not HAS_PALLAS_TPU:
-    pytest.skip(
-        "jax.experimental.pallas.tpu is not importable in this JAX build",
-        allow_module_level=True,
-    )
-
 from repro.kernels.bitplane import (
     bitplane_decode,
     bitplane_encode,

@@ -7,6 +7,7 @@ bounded decode-state LRU, gauge metrics, the coalescing async service
 accounting fixes in ``launch/serve``.
 """
 import asyncio
+import os
 
 import numpy as np
 import pytest
@@ -390,6 +391,20 @@ class TestOffloadService:
                     assert np.array_equal(a, decompress_chunk(blob, c))
 
         asyncio.run(run())
+
+    def test_process_workers_are_host_only(self, monkeypatch):
+        """Spawned workers pin JAX to the CPU whatever the parent's
+        environment says: the parent holds the chip."""
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+
+        async def run():
+            async with OffloadService(workers=1, executor="process") as svc:
+                loop = svc._ensure_started()
+                return await loop.run_in_executor(
+                    svc._executor, os.getenv, "JAX_PLATFORMS"
+                )
+
+        assert asyncio.run(run()) == "cpu"
 
     def test_service_survives_two_event_loops(self, container):
         _, blob = container
