@@ -144,7 +144,7 @@ class Sz3Codec(_CodecBase):
 
     # -- numcodecs protocol ---------------------------------------------------
     def encode(self, buf) -> bytes:
-        data = np.asarray(buf)
+        data = pl_mod.to_host(buf)
         if data.dtype.kind not in "fiu":
             raise TypeError(
                 f"Sz3Codec encodes numeric arrays, got dtype {data.dtype}"

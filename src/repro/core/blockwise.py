@@ -273,7 +273,7 @@ class BlockHybridCompressor:
         with_stats: bool = False,
     ) -> CompressionResult:
         conf = conf or self.conf
-        data = np.asarray(data)
+        data = pl_mod.to_host(data)
         if data.dtype not in (np.float32, np.float64):
             data = data.astype(np.float32)
         pre = self.preprocessor
@@ -282,10 +282,7 @@ class BlockHybridCompressor:
             # pointwise bound holds by construction (no eb*absmax degradation)
             pre = pre_mod.LogTransform()
         pdata, conf2, pre_meta = pre.forward(data, conf)
-        rng, absmax = pl_mod._finite_stats(pdata)
-        abs_eb = conf2.resolve_abs_eb(rng, absmax)
-        if abs_eb <= 0:
-            abs_eb = float(np.finfo(np.float64).tiny)
+        abs_eb = pl_mod.resolve_bound(pdata, conf2)
         self.quantizer.begin(abs_eb, pdata.dtype)
         with tel.span("predict", bytes=pdata.nbytes):  # per-block contest
             codes, tag_bytes, hmeta = self._compress_blocks(pdata, conf2)

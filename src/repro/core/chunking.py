@@ -516,7 +516,7 @@ class ChunkedCompressor:
         parallel traces merge deterministically) and a schema-pinned
         selection-decision record is emitted in chunk order from the ordered
         consumer side — never from racing worker threads."""
-        data = np.asarray(data)
+        data = pl_mod.to_host(data)
         if data.dtype not in (np.float32, np.float64):
             data = data.astype(np.float32)
         if conf.mode == ErrorBoundMode.PW_REL:
@@ -526,10 +526,7 @@ class ChunkedCompressor:
             abs_eb = pre_mod.pw_rel_log_eb(conf.eb)
             eff = conf
         else:
-            rng, absmax = pl_mod._finite_stats(data)
-            abs_eb = conf.resolve_abs_eb(rng, absmax)
-            if abs_eb <= 0:
-                abs_eb = float(np.finfo(np.float64).tiny)
+            abs_eb = pl_mod.resolve_bound(data, conf)
             eff = conf.replace(mode=ErrorBoundMode.ABS, eb=abs_eb)
         flat_leading = data.reshape(-1) if data.ndim == 0 else data
         chunks = (
@@ -573,7 +570,7 @@ class ChunkedCompressor:
         with_stats: bool = False,
     ) -> CompressionResult:
         conf = conf or self.conf
-        data = np.asarray(data)
+        data = pl_mod.to_host(data)
         stored_dtype = (
             data.dtype if data.dtype in (np.float32, np.float64) else np.dtype(np.float32)
         )
@@ -665,21 +662,21 @@ def decompress_chunked(
     body = pl_mod.container_body(blob, body_off)
     bounds = integrity.chunk_bounds_of(header, len(body))
     nested = "off" if verify == "off" else "strict"
-    parts = list(
-        _parallel_map_ordered(
-            lambda b: pl_mod.decompress(body[b[0] : b[0] + b[1]], verify=nested),
-            bounds,
-            workers,
-        )
-    )
+
+    def _one(args: Tuple[int, Tuple[int, int]]) -> np.ndarray:
+        i, (off, length) = args
+        with tel.span("chunk", order=i, bytes=length):
+            return pl_mod.decompress(body[off : off + length], verify=nested)
+
+    parts = list(_parallel_map_ordered(_one, enumerate(bounds), workers))
     dtype = np.dtype(header["dtype"])
     shape = guard_shape(header["shape"], dtype.itemsize, "shape")
     if not parts:
         return np.zeros(shape, dtype)
-    if parts[0].ndim == 0 or not shape:
-        out = np.concatenate([np.atleast_1d(p) for p in parts])
-        return out.astype(dtype).reshape(shape)
-    return np.concatenate(parts, axis=0).astype(dtype).reshape(shape)
+    with tel.span("unpack", bytes=int(np.prod(shape)) * dtype.itemsize):
+        if parts[0].ndim == 0 or not shape:
+            parts = [np.atleast_1d(p) for p in parts]
+        return np.concatenate(parts, axis=0).astype(dtype).reshape(shape)
 
 
 def salvage_chunked(

@@ -234,7 +234,7 @@ class FastModeCompressor:
         with_stats: bool = False,
     ) -> CompressionResult:
         conf = conf or self.conf
-        data = np.asarray(data)
+        data = pl_mod.to_host(data)
         if data.dtype not in (np.float32, np.float64):
             data = data.astype(np.float32)
         pre = self.preprocessor
@@ -243,10 +243,7 @@ class FastModeCompressor:
             # pointwise bound holds by construction
             pre = pre_mod.LogTransform()
         pdata, conf2, pre_meta = pre.forward(data, conf)
-        rng, absmax = pl_mod._finite_stats(pdata)
-        abs_eb = conf2.resolve_abs_eb(rng, absmax)
-        if abs_eb <= 0:
-            abs_eb = float(np.finfo(np.float64).tiny)
+        abs_eb = pl_mod.resolve_bound(pdata, conf2)
         with tel.span("quantize", bytes=pdata.nbytes):
             body_parts, fmeta = self._encode_blocks(pdata, abs_eb)
         spec = self.spec()
@@ -343,8 +340,9 @@ class FastModeCompressor:
             # never widen the bound)
             const = dev_hint <= eb_strict
             if const.any():
-                with np.errstate(invalid="ignore"):
+                with tel.span("verify") as sp, np.errstate(invalid="ignore"):
                     exact = np.abs(resid[const]).max(axis=1) <= eb_strict
+                    sp.set(bytes=exact.size * bs * pdtype.itemsize)
                 idx = np.flatnonzero(const)
                 const[idx[~exact]] = False
             gmin = gmax = None  # hint is approximate; probe exactly below
@@ -389,24 +387,25 @@ class FastModeCompressor:
             # bound is stored raw.  After rint, resid == q in the work dtype
             # (both sides of the int32 round trip are exact), so the all-
             # nonconstant case reuses the resid buffer outright.
-            if all_nc:
-                err, x_nc, means_nc = resid, xb, means_st
-            else:
-                err = q.astype(pdtype)
-                x_nc, means_nc = xb[nonconst], means_st[nonconst]
-            np.multiply(err, twoeb, out=err)
-            np.add(means_nc[:, None], err, out=err)
-            np.subtract(x_nc, err, out=err)  # err is now the coding error
-            np.abs(err, out=err)
-            with np.errstate(invalid="ignore"):
-                fail_mask = ~(err <= eb_strict)
-            if fail_mask.any():
-                # fail positions in the ORIGINAL flat index space (row-major
-                # nonzero keeps them sorted; padding cropped)
-                block_idx = np.flatnonzero(nonconst)
-                rows, cols = np.nonzero(fail_mask)
-                ff = block_idx[rows] * bs + cols
-                fail_idx = ff[ff < n].astype(np.int64)
+            with tel.span("verify", bytes=n_nc * bs * pdtype.itemsize):
+                if all_nc:
+                    err, x_nc, means_nc = resid, xb, means_st
+                else:
+                    err = q.astype(pdtype)
+                    x_nc, means_nc = xb[nonconst], means_st[nonconst]
+                np.multiply(err, twoeb, out=err)
+                np.add(means_nc[:, None], err, out=err)
+                np.subtract(x_nc, err, out=err)  # err is now the coding error
+                np.abs(err, out=err)
+                with np.errstate(invalid="ignore"):
+                    fail_mask = ~(err <= eb_strict)
+                if fail_mask.any():
+                    # fail positions in the ORIGINAL flat index space (row-
+                    # major nonzero keeps them sorted; padding cropped)
+                    block_idx = np.flatnonzero(nonconst)
+                    rows, cols = np.nonzero(fail_mask)
+                    ff = block_idx[rows] * bs + cols
+                    fail_idx = ff[ff < n].astype(np.int64)
             w = _required_bits(np.maximum(q.max(axis=1), -q.min(axis=1)))
         const_bytes = np.packbits(const).tobytes()
         means_bytes = means_st.tobytes()

@@ -77,6 +77,28 @@ def _finite_stats(data: np.ndarray) -> Tuple[float, float]:
     return mx - mn, max(abs(mn), abs(mx))
 
 
+def resolve_bound(data: np.ndarray, conf: CompressionConfig) -> float:
+    """The absolute bound ``conf`` resolves to on ``data`` (never 0): the
+    finite-range pass and the resolution, in a ``stats`` span."""
+    with tel.span("stats", bytes=data.nbytes):
+        rng, absmax = _finite_stats(data)
+        abs_eb = conf.resolve_abs_eb(rng, absmax)
+    if abs_eb <= 0:
+        abs_eb = float(np.finfo(np.float64).tiny)
+    return abs_eb
+
+
+def to_host(x) -> np.ndarray:
+    """``x`` as a numpy array.  Anything else, a device array above all, is
+    read to the host in a ``to_host`` span."""
+    if isinstance(x, np.ndarray):
+        return x
+    with tel.span("to_host") as sp:
+        out = np.asarray(x)
+        sp.set(bytes=out.nbytes)
+    return out
+
+
 def _clean_meta(meta: Dict[str, Any]) -> Dict[str, Any]:
     """Coerce numpy scalars so msgpack accepts the header."""
     out = {}
@@ -111,16 +133,17 @@ def pack_container(
     under the header checksum so strict verification can detect a stripped
     trailer.  ``integrity.trailers_disabled()`` suppresses both (overhead
     benchmarking, legacy-fixture generation)."""
-    if integrity.WRITE_TRAILERS:
-        header = dict(header)
-        header["itg"] = 1
-    hbytes = msgpack.packb(header, use_bin_type=True)
-    head = _MAGIC + np.asarray([len(hbytes), len(body)], np.int64).tobytes() + hbytes
-    if not integrity.WRITE_TRAILERS:
-        return head + body
-    with tel.span("integrity", bytes=len(body)):
-        trailer = integrity.build_trailer(head, body, chunk_bounds)
-    return head + body + trailer
+    with tel.span("pack", bytes=len(body)):
+        if integrity.WRITE_TRAILERS:
+            header = dict(header)
+            header["itg"] = 1
+        hbytes = msgpack.packb(header, use_bin_type=True)
+        head = _MAGIC + np.asarray([len(hbytes), len(body)], np.int64).tobytes() + hbytes
+        if not integrity.WRITE_TRAILERS:
+            return head + body
+        with tel.span("integrity", bytes=len(body)):
+            trailer = integrity.build_trailer(head, body, chunk_bounds)
+        return head + body + trailer
 
 
 def container_body(blob: bytes, body_off: int) -> bytes:
@@ -186,14 +209,11 @@ class SZ3Compressor:
         self, data: np.ndarray, conf: CompressionConfig = None, with_stats: bool = False
     ) -> CompressionResult:
         conf = conf or self.conf
-        data = np.asarray(data)
+        data = to_host(data)
         if data.dtype not in (np.float32, np.float64):
             data = data.astype(np.float32)
         pdata, conf2, pre_meta = self.preprocessor.forward(data, conf)  # line 1
-        rng, absmax = _finite_stats(pdata)
-        abs_eb = conf2.resolve_abs_eb(rng, absmax)
-        if abs_eb <= 0:
-            abs_eb = np.finfo(np.float64).tiny
+        abs_eb = resolve_bound(pdata, conf2)
         self.quantizer.begin(abs_eb, pdata.dtype)
         with tel.span("predict", bytes=pdata.nbytes):  # predict+quantize fused
             codes, pred_meta = self.predictor.compress(pdata, self.quantizer, conf2)  # 2-5

@@ -443,16 +443,17 @@ class LorenzoPredictor(Predictor):
         eb = quantizer.eb
         with tel.span("device_transfer", bytes=data.nbytes):
             codes32, draw = lops.encode_pipeline(data, eb=eb, radius=quantizer.radius)
-        d = draw.astype(np.int64)
-        x64 = np.asarray(data, np.float64)
         # The kernel prequantizes in float32 (vs float64 on the numpy route);
         # verify the bound against BOTH decode routes' exact arithmetic and
         # divert any straggler through the fail channel (raw values).
-        q = lorenzo_inverse(d, 1)
-        recon_np = quantizer.dequantize_int(q)
-        fail = np.abs(recon_np.astype(np.float64) - x64) > eb
-        recon_dev = lops.decode_pipeline(draw, eb=eb)
-        fail |= np.abs(recon_dev.astype(np.float64) - x64) > eb
+        with tel.span("verify", bytes=data.nbytes):
+            d = draw.astype(np.int64)
+            x64 = np.asarray(data, np.float64)
+            q = lorenzo_inverse(d, 1)
+            recon_np = quantizer.dequantize_int(q)
+            fail = np.abs(recon_np.astype(np.float64) - x64) > eb
+            recon_dev = lops.decode_pipeline(draw, eb=eb)
+            fail |= np.abs(recon_dev.astype(np.float64) - x64) > eb
         flat = d.reshape(-1)
         oor = np.abs(flat) >= quantizer.radius
         if oor.any():
@@ -495,7 +496,8 @@ class LorenzoPredictor(Predictor):
             # arithmetic, so the fused route is bound-exact here
             from ..kernels.lorenzo import ops as lops
 
-            out = lops.decode_pipeline(d.astype(np.int32), eb=quantizer.eb).astype(dtype)
+            with tel.span("device_transfer", bytes=d.size * 4):
+                out = lops.decode_pipeline(d.astype(np.int32), eb=quantizer.eb).astype(dtype)
         else:
             q = lorenzo_inverse(d, order)
             out = quantizer.dequantize_int(q).astype(dtype)

@@ -5,11 +5,15 @@ engine in the repo.  Three layers, cheapest first:
 
   * **Stage spans** — nestable timed scopes named after the pipeline stages
     (``predict``, ``quantize``, ``huffman``, ``lossless``, ``integrity``,
-    ``device_transfer``) recorded into a context-var-scoped :class:`Trace`.
-    When no trace is active, :func:`span` returns a module-level no-op
-    singleton: the disabled path is one ``ContextVar.get`` plus a comparison,
-    so instrumented hot loops pay well under 1% (gated in CI by
-    ``benchmarks/check_regression.py``).
+    ``device_transfer``, ...) recorded into a context-var-scoped
+    :class:`Trace`.  When no trace is active, :func:`span` returns a
+    module-level no-op singleton: the disabled path is one ``ContextVar.get``
+    plus a comparison.  A trace may carry an annotation factory
+    (``trace(name, annotate=...)``); each span then also opens
+    ``annotate("sz3." + name)`` around its timed scope, which puts the spans
+    on another tracer's clock (``jax.profiler.TraceAnnotation`` puts them on
+    the profiler's host line, beside the device ops) without this module
+    importing it.
   * **Selection-decision records** — every engine that runs a contest
     (per-chunk pipeline selection, per-block predictor tags, constant-vs-
     fixed-length) emits a schema-pinned record of who contested, who won,
@@ -61,14 +65,10 @@ __all__ = [
     "prometheus_text",
     "reset_metrics",
     "get_logger",
-    "STAGES",
 ]
 
-#: canonical stage-span names (engines may add engine-specific ones, e.g.
-#: "chunk"/"select"/"leaf"; exporters treat any name uniformly)
-STAGES = (
-    "predict", "quantize", "huffman", "lossless", "integrity", "device_transfer",
-)
+#: what :class:`Span` prefixes its name with for a trace's annotation factory
+_ANNOTATION_PREFIX = "sz3."
 
 LOG_LEVEL_ENV = "SZ3J_LOG_LEVEL"
 
@@ -167,7 +167,9 @@ class StreamingHistogram:
 class Span:
     """One timed scope.  Created via :func:`span`; use as a context manager."""
 
-    __slots__ = ("name", "attrs", "children", "seconds", "_trace", "_t0", "_token")
+    __slots__ = (
+        "name", "attrs", "children", "seconds", "_trace", "_t0", "_token", "_ann",
+    )
 
     def __init__(self, name: str, attrs: Dict[str, Any], trace: "Trace"):
         self.name = name
@@ -177,6 +179,7 @@ class Span:
         self._trace = trace
         self._t0 = 0.0
         self._token = None
+        self._ann = None
 
     def set(self, **attrs: Any) -> "Span":
         self.attrs.update(attrs)
@@ -184,6 +187,9 @@ class Span:
 
     def __enter__(self) -> "Span":
         tr = self._trace
+        if tr.annotate is not None:
+            self._ann = tr.annotate(_ANNOTATION_PREFIX + self.name)
+            self._ann.__enter__()
         parent = tr._span_var.get() or tr.root
         with tr._lock:
             parent.children.append(self)
@@ -194,6 +200,8 @@ class Span:
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.seconds = time.perf_counter() - self._t0
         self._trace._span_var.reset(self._token)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
     def to_dict(self) -> Dict[str, Any]:
@@ -241,10 +249,15 @@ class Trace:
     :func:`count`, :func:`observe` and :func:`record_decision` inside the
     block (including worker threads entered via :func:`propagate`) lands in
     ``tr``.  Traces may nest; the innermost active trace receives events.
+    ``annotate``, when given, is called with ``"sz3." + span name`` for each
+    span and must return a context manager, entered and exited around the
+    span's timed scope in the thread that runs it.
     """
 
-    def __init__(self, name: str = "trace"):
+    def __init__(self, name: str = "trace",
+                 annotate: Optional[Callable[[str], Any]] = None):
         self.name = name
+        self.annotate = annotate
         self.root = Span("root", {}, self)
         self._lock = threading.Lock()
         # current open span, per thread/context — worker threads start fresh
@@ -391,9 +404,11 @@ class _TraceScope:
         return False
 
 
-def trace(name: str = "trace") -> _TraceScope:
-    """``with telemetry.trace("compress") as tr:`` — activate a new trace."""
-    return _TraceScope(Trace(name))
+def trace(name: str = "trace",
+          annotate: Optional[Callable[[str], Any]] = None) -> _TraceScope:
+    """``with telemetry.trace("compress") as tr:`` — activate a new trace
+    (``annotate``: see :class:`Trace`)."""
+    return _TraceScope(Trace(name, annotate))
 
 
 def current() -> Optional[Trace]:

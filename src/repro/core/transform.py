@@ -243,16 +243,17 @@ class TransformCompressor:
         with_stats: bool = False,
     ) -> CompressionResult:
         conf = conf or self.conf
-        data = np.asarray(data)
+        data = pl_mod.to_host(data)
         if data.dtype not in (np.float32, np.float64):
             data = data.astype(np.float32)
         shape = data.shape
         x = data.reshape(1) if data.ndim == 0 else data
-        x64 = np.asarray(x, np.float64)
-        finite = np.isfinite(x64)
-        rng = float(x64[finite].max() - x64[finite].min()) if finite.any() else 0.0
-        absmax = float(np.abs(x64[finite]).max()) if finite.any() else 0.0
-        abs_eb = conf.resolve_abs_eb(rng, absmax)
+        with tel.span("stats", bytes=x.nbytes):
+            x64 = np.asarray(x, np.float64)
+            finite = np.isfinite(x64)
+            rng = float(x64[finite].max() - x64[finite].min()) if finite.any() else 0.0
+            absmax = float(np.abs(x64[finite]).max()) if finite.any() else 0.0
+            abs_eb = conf.resolve_abs_eb(rng, absmax)
         if abs_eb <= 0:
             abs_eb = float(np.finfo(np.float64).tiny)
         meta: Dict[str, Any] = {}
@@ -283,17 +284,19 @@ class TransformCompressor:
         # patches survive the cast exactly: they carry the original values);
         # stragglers ride the fail channel
         crop = tuple(slice(0, s) for s in x.shape)
-        recon = _inv_host(k.astype(np.float64) * step)[crop]
-        recon_cast = recon.astype(data.dtype).astype(np.float64)
-        fail = ~finite | (np.abs(recon_cast - x64) > abs_eb)
-        if device:
-            from ..kernels.transform import ops as tops
+        with tel.span("verify", bytes=x.nbytes):
+            recon = _inv_host(k.astype(np.float64) * step)[crop]
+            recon_cast = recon.astype(data.dtype).astype(np.float64)
+            fail = ~finite | (np.abs(recon_cast - x64) > abs_eb)
+            if device:
+                from ..kernels.transform import ops as tops
 
-            recon_dev = np.asarray(
-                tops.inv_pipeline((k.astype(np.float64) * step).astype(np.float32)),
-                np.float64,
-            )[crop].astype(data.dtype).astype(np.float64)
-            fail |= np.abs(recon_dev - x64) > abs_eb
+                recon_dev = np.asarray(
+                    tops.inv_pipeline((k.astype(np.float64) * step).astype(np.float32)),
+                    np.float64,
+                )[crop].astype(data.dtype).astype(np.float64)
+                fail |= np.abs(recon_dev - x64) > abs_eb
+        if device:
             meta["device"] = 1
             # the kernel-inverse verification above only covers THIS
             # backend's arithmetic; decode takes the device route only when
@@ -381,12 +384,14 @@ class TransformCompressor:
         ):
             from ..kernels.transform import ops as tops
 
-            out = np.asarray(
-                tops.inv_pipeline((k.astype(np.float64) * step).astype(np.float32)),
-                np.float64,
-            )[crop]
+            with tel.span("device_transfer", bytes=k.size * 4):
+                out = np.asarray(
+                    tops.inv_pipeline((k.astype(np.float64) * step).astype(np.float32)),
+                    np.float64,
+                )[crop]
         else:
-            out = _inv_host(k.astype(np.float64) * step)[crop]
+            with tel.span("predict", bytes=k.size * 8):  # the inverse transform
+                out = _inv_host(k.astype(np.float64) * step)[crop]
         if meta.get("nfail"):
             n = int(np.prod(shape)) if shape else 1
             mask = _unpack_mask(meta["fail_mask"], n).reshape(out.shape)

@@ -262,7 +262,6 @@ class CheckpointManager:
             meta["seconds"] = round(d_leaf, 6)
             meta["ratio"] = round(arr.nbytes / max(1, len(blob)), 4)
             telemetry.metric_observe("sz3_checkpoint_leaf_seconds", d_leaf)
-            telemetry.observe("checkpoint_leaf_seconds", d_leaf)
             fname = hashlib.sha1(pstr.encode()).hexdigest()[:16] + ".bin"
             (tmp / fname).write_bytes(blob)
             meta["file"] = fname

@@ -11,6 +11,7 @@ exposition, and the structured key=value logger.
 import concurrent.futures as cf
 import json
 import logging
+import threading
 
 import numpy as np
 import pytest
@@ -171,6 +172,141 @@ def test_serial_parallel_traces_structurally_identical():
 
     assert blobs[0] == blobs[1]
     assert strip(trees[0]) == strip(trees[1])
+
+
+# ---------------------------------------------------------------------------
+# annotation factory: spans on another tracer's clock
+# ---------------------------------------------------------------------------
+
+class _Annotator:
+    """Records the enter/exit of every annotation it hands out, per thread."""
+
+    def __init__(self):
+        self.events = []
+        self.calls = 0
+
+    def __call__(self, name):
+        self.calls += 1
+        outer = self
+
+        class _Scope:
+            def __enter__(self):
+                outer.events.append(("enter", name, threading.get_ident()))
+
+            def __exit__(self, *exc):
+                outer.events.append(("exit", name, threading.get_ident()))
+
+        return _Scope()
+
+
+def _balanced(events):
+    """True when each thread's enter/exit events nest like brackets."""
+    stacks = {}
+    for kind, name, thread in events:
+        stack = stacks.setdefault(thread, [])
+        if kind == "enter":
+            stack.append(name)
+        elif not stack or stack.pop() != name:
+            return False
+    return all(not s for s in stacks.values())
+
+
+def test_annotator_sees_spans_in_nesting_order():
+    ann = _Annotator()
+
+    def work(i):
+        with telemetry.span("chunk", order=i):
+            with telemetry.span("huffman"):
+                pass
+
+    with telemetry.trace("t", annotate=ann):
+        with telemetry.span("outer"):
+            with telemetry.span("inner"):
+                pass
+        with cf.ThreadPoolExecutor(max_workers=2) as pool:
+            list(pool.map(telemetry.propagate(work), range(2)))
+    main = threading.get_ident()
+    assert [(k, n) for k, n, t in ann.events if t == main] == [
+        ("enter", "sz3.outer"), ("enter", "sz3.inner"),
+        ("exit", "sz3.inner"), ("exit", "sz3.outer"),
+    ]
+    workers = [e for e in ann.events if e[2] != main]
+    assert len(workers) == 8  # two chunks, each chunk > huffman, in a worker
+    assert _balanced(ann.events)
+    for thread in {t for _, _, t in workers}:
+        names = [n for _, n, t in workers if t == thread]
+        assert names[:2] == ["sz3.chunk", "sz3.huffman"]
+
+
+def test_annotator_unused_without_trace():
+    ann = _Annotator()
+    with telemetry.trace("t", annotate=ann):
+        pass
+    with telemetry.span("predict") as sp:  # no trace active any more
+        assert sp is telemetry.span("huffman")
+    assert ann.calls == 0
+
+
+@pytest.mark.parametrize("predictor", ["auto", "lorenzo"])
+def test_annotated_trace_leaves_blobs_alone(predictor):
+    from repro.codec import Sz3Codec
+
+    data = np.cumsum(
+        np.random.default_rng(11).standard_normal((96, 160)).astype(np.float32), 0
+    )
+    codec = Sz3Codec(eb_mode="rel", eb_rel=1e-3, predictor=predictor)
+    with telemetry.trace("t"):
+        plain = codec.encode(data)
+    with telemetry.trace("t", annotate=_Annotator()):
+        annotated = codec.encode(data)
+    assert annotated == plain
+
+
+@pytest.mark.parametrize("predictor", ["auto", "lorenzo"])
+def test_codec_emits_host_stage_spans(predictor):
+    import jax.numpy as jnp
+
+    from repro.codec import Sz3Codec
+
+    data = np.cumsum(
+        np.random.default_rng(12).standard_normal((96, 160)).astype(np.float32), 0
+    )
+    codec = Sz3Codec(eb_mode="rel", eb_rel=1e-3, predictor=predictor)
+    with telemetry.trace("t") as tr:
+        blob = codec.encode(jnp.asarray(data))
+    totals = tr.stage_totals()
+    for stage in ("to_host", "stats", "pack", "integrity"):
+        assert totals[stage]["calls"] >= 1, stage
+    assert totals["to_host"]["calls"] == 1
+    assert totals["to_host"]["bytes"] == data.nbytes
+    (pack,) = [s for s in tr.root.children if s.name == "pack"]
+    assert [c.name for c in pack.children] == ["integrity"]
+    with telemetry.trace("t") as tr:
+        out = codec.decode(blob)
+    assert out.shape == data.shape
+    names = set(tr.stage_totals())
+    assert "huffman" in names and "to_host" not in names
+    if predictor == "auto":
+        chunks = [s for s in tr.root.children if s.name == "chunk"]
+        assert [s.attrs["order"] for s in chunks] == list(range(len(chunks)))
+        assert chunks and "unpack" in names
+
+
+def test_device_lorenzo_route_emits_verify():
+    from repro.core import SZ3Compressor
+    from repro.core.predictors import LorenzoPredictor
+
+    x = np.cumsum(
+        np.random.default_rng(13).standard_normal((64, 256)).astype(np.float32), -1
+    )
+    comp = SZ3Compressor(predictor=LorenzoPredictor(device="force"))
+    with telemetry.trace("t") as tr:
+        comp.compress(x, CompressionConfig(mode=ErrorBoundMode.ABS, eb=1e-3))
+    (predict,) = [s for s in tr.root.children if s.name == "predict"]
+    assert [c.name for c in predict.children] == ["device_transfer", "verify"]
+    (verify,) = [c for c in predict.children if c.name == "verify"]
+    assert verify.children == []  # its device decode is no device_transfer
+    assert verify.attrs["bytes"] == x.nbytes
 
 
 # ---------------------------------------------------------------------------
