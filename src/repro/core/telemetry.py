@@ -49,6 +49,7 @@ __all__ = [
     "current",
     "enabled",
     "span",
+    "tag",
     "count",
     "observe",
     "record_decision",
@@ -427,6 +428,17 @@ def span(name: str, **attrs: Any):
     if tr is None:
         return _NOOP_SPAN
     return Span(name, attrs, tr)
+
+
+def tag(name: str, **attrs: Any) -> None:
+    """Set ``attrs`` on the innermost span open in this context if it is
+    named ``name`` (code inside a span records what it decided, without
+    opening a span of its own); no-op otherwise."""
+    tr = _trace_var.get()
+    if tr is not None:
+        sp = tr._span_var.get()
+        if sp is not None and sp.name == name:
+            sp.attrs.update(attrs)
 
 
 def count(name: str, inc: Union[int, float] = 1) -> None:

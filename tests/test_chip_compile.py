@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from repro.compression import grad as gradc
 from repro.core import jitmode
+from repro.kernels import huffman
 from repro.kernels.fastmode import ops as fops
 from repro.kernels.lorenzo import ops as lops
 from repro.kernels.transform import ops as tops
@@ -94,6 +95,18 @@ def test_fastmode_stats_compile(one_chip):
     nb = -(-1800 * 3600 // 256 // 256) * 256
     x = _sds((nb, 256), jnp.float32, one_chip)
     _pallas_text(fops._stats_padded.lower(x, bm=256, interpret=False).compile())
+
+
+# an auto chunk at 4.5 bits a code; a 1800x3600 field at 4 and at 16
+@pytest.mark.parametrize("size,log2_words", [(1 << 20, 18), (13 << 19, 20), (13 << 19, 22)])
+def test_huffman_pack_compiles(one_chip, size, log2_words):
+    syms = _sds((size,), jnp.uint16, one_chip)
+    table = _sds((huffman.TABLE_SIZE,), jnp.uint32, one_chip)
+    n = _sds((), jnp.int32, one_chip)
+    compiled = huffman.huffman_pack.lower(syms, table, n, n_words=1 << log2_words).compile()
+    sync, pieces = compiled.out_info
+    assert sync.shape == (size // 1024,)
+    assert sum(p.shape[0] for p in pieces) == 1 << log2_words
 
 
 @pytest.mark.parametrize("tier", ["int8", "int4"])
