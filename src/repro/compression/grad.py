@@ -35,6 +35,8 @@ from ..core import jitmode
 from ..core.jitmode import JitPolicy
 
 BLOCK = 512
+#: name scope of the reduction's stages in the compiled program
+SCOPE = "sz3.grad"
 SCALE_FLOOR = jitmode.SCALE_FLOOR
 
 #: each DP shard holds a whole number of 8-block row groups.  The TPU tiles
@@ -118,21 +120,27 @@ def compressed_reduce_flat(
     n = flat.shape[0]
     pad = feedback.shape[0] * dp - n  # the feedback fixes the shard length
     assert pad >= 0, "feedback shard shorter than the gradient vector"
-    fp = jnp.pad(flat, (0, pad)).astype(jnp.bfloat16)
-    shard = jax.lax.psum_scatter(fp, axes, scatter_dimension=0, tiled=True)
-    shard = shard.astype(jnp.float32) / dp + feedback
+    # each stage under a "sz3.grad/<stage>" scope, so a device profile's op
+    # metadata tells the codec from the model
+    with jax.named_scope(f"{SCOPE}/reduce_scatter"):
+        fp = jnp.pad(flat, (0, pad)).astype(jnp.bfloat16)
+        shard = jax.lax.psum_scatter(fp, axes, scatter_dimension=0, tiled=True)
+        shard = shard.astype(jnp.float32) / dp + feedback
     m = shard.shape[0]
-    c = jitmode.encode(shard, pol)
-    new_feedback = shard - jitmode.decode(c)
-    gathered = jax.tree.map(
-        lambda a: jax.lax.all_gather(a, axes, tiled=True), c
-    )
-    # each shard's blocks carry their own tail padding (m need not divide
-    # the block size), so crop per shard before re-flattening
-    xb = jitmode.decode_blocks(
-        gathered.codes, gathered.scale, gathered.tags, gathered.base, pol.bits
-    )
-    out = xb.reshape(dp, -1)[:, :m].reshape(-1)[:n]
+    with jax.named_scope(f"{SCOPE}/encode"):
+        c = jitmode.encode(shard, pol)
+        new_feedback = shard - jitmode.decode(c)
+    with jax.named_scope(f"{SCOPE}/all_gather"):
+        gathered = jax.tree.map(
+            lambda a: jax.lax.all_gather(a, axes, tiled=True), c
+        )
+    with jax.named_scope(f"{SCOPE}/decode"):
+        # each shard's blocks carry their own tail padding (m need not
+        # divide the block size), so crop per shard before re-flattening
+        xb = jitmode.decode_blocks(
+            gathered.codes, gathered.scale, gathered.tags, gathered.base, pol.bits
+        )
+        out = xb.reshape(dp, -1)[:, :m].reshape(-1)[:n]
     return out, new_feedback
 
 

@@ -1,5 +1,5 @@
 """qwen1.5-0.5b [hf:Qwen/Qwen1.5-0.5B; hf] — MHA with QKV bias, tied embeds.
-24L d_model=1024 16H (kv=16) d_ff=2816 vocab=151936.
+24L d_model=1024 16H (kv=16) d_ff=2816 vocab=151936 rope_theta=1e6.
 Full attention => long_500k SKIPPED."""
 from repro.models.common import ModelConfig
 
@@ -15,6 +15,7 @@ CONFIG = ModelConfig(
     qkv_bias=True,
     tie_embeddings=True,
     mlp_act="swiglu",
+    rope_theta=1_000_000.0,
 )
 
 SMOKE = ModelConfig(

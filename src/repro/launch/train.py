@@ -8,19 +8,29 @@ resumable data, heartbeat monitoring.  ``main(argv)`` returns the final
 state, the per-step losses and wall seconds (the first step's include its
 compilation) and the checkpoint manager, so a driver script can check them
 in the same process.
+
+Two functions carry the run, and the chip benchmark calls the same two:
+:func:`build` makes the configuration, plan, optimizer, state, jitted step
+and data pipeline from the command line's arguments, and :func:`step_loop`
+runs the step for each step index.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 import repro.configs as configs
+from repro.compression import grad as gradc
+from repro.core import telemetry
 from repro.data import make_pipeline
 from repro.ft import CheckpointManager, HeartbeatMonitor
+from repro.models.common import ModelConfig
 from repro.optim import AdamWConfig
 from repro.parallel import ParallelPlan
 from repro.train.step import (
@@ -33,15 +43,18 @@ from repro.train.step import (
 from .compile_cache import use_compile_cache
 
 
-def main(argv=None):
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b", choices=configs.ARCHS)
     ap.add_argument("--smoke", action=argparse.BooleanOptionalAction, default=True,
                     help="use the reduced config; --no-smoke runs the "
                          "published widths")
-    ap.add_argument("--steps", type=int, default=30)
+    ap.add_argument("--steps", type=int, default=30,
+                    help="steps to run, and the learning-rate schedule's horizon")
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--batch", type=int, default=4)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seeds the initial parameters and the token stream")
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_launch_train",
@@ -61,7 +74,37 @@ def main(argv=None):
     ap.add_argument("--compress-opt", default="", metavar="POLICY",
                     help="compressed optimizer moments with this jitmode "
                          "policy spec (implies --compress-moments)")
-    args = ap.parse_args(argv)
+    return ap
+
+
+@dataclasses.dataclass
+class TrainRun:
+    """A training run as :func:`build` makes it.  ``state`` is the live
+    train state: :func:`step_loop` replaces it after every step (the step
+    donates its input)."""
+
+    args: argparse.Namespace
+    cfg: ModelConfig
+    plan: ParallelPlan
+    opt: AdamWConfig
+    state: Any
+    step_fn: Callable
+    pipe: Any
+    #: first step index: 0, or the step after the newest checkpoint
+    start: int = 0
+    ckpt: Optional[CheckpointManager] = None
+
+    @property
+    def tokens_per_step(self) -> int:
+        return self.args.batch * self.args.seq
+
+
+def build(argv: Optional[Sequence[str]] = None, *, checkpoints: bool = False) -> TrainRun:
+    """Configuration, plan, optimizer, state, jitted step and data pipeline
+    from the command line's arguments.  With ``checkpoints`` the run keeps
+    a :class:`CheckpointManager` in ``--ckpt-dir`` and resumes from its
+    newest checkpoint; without, it reads and writes no file."""
+    args = _parser().parse_args(argv)
     use_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
@@ -87,15 +130,12 @@ def main(argv=None):
         compress_moments=args.compress_moments or bool(args.compress_opt),
         moment_policy=args.compress_opt,
     )
-    print(f"arch={cfg.name} family={cfg.family} ~{cfg.n_flop_params()/1e6:.0f}M params")
+    pipe = make_pipeline(cfg, seq=args.seq, global_batch=args.batch, seed=args.seed)
+    mgr = CheckpointManager(args.ckpt_dir, keep=2) if checkpoints else None
 
-    pipe = make_pipeline(cfg, seq=args.seq, global_batch=args.batch)
-    mgr = CheckpointManager(args.ckpt_dir, keep=2)
-    mon = HeartbeatMonitor(["host0"], timeout_s=600)
-
-    state = init_train_state(jax.random.PRNGKey(0), cfg, plan, opt)
+    state = init_train_state(jax.random.PRNGKey(args.seed), cfg, plan, opt)
     start = 0
-    if mgr.list_steps():
+    if mgr is not None and mgr.list_steps():
         host, extra = mgr.restore(jax.tree.map(np.asarray, state))
         state = jax.tree.map(jnp.asarray, host)
         start = int(extra.get("next_step", 0))
@@ -110,24 +150,64 @@ def main(argv=None):
         batch0 = {k: jnp.asarray(v) for k, v in pipe.batch_at(start).items()}
         step_fn = jit_train_step(step_fn, state, cfg, plan, opt, batch0)
         state = jax.device_put(state, state_shardings(state, cfg, plan, opt))
-    losses, seconds = [], []
-    t0 = time.perf_counter()
-    for k in range(start, args.steps):
-        batch = {k2: jnp.asarray(v) for k2, v in pipe.batch_at(k).items()}
-        state, m = step_fn(state, batch)
-        losses.append(float(m["loss"]))
-        dt = time.perf_counter() - t0
+    return TrainRun(args=args, cfg=cfg, plan=plan, opt=opt, state=state,
+                    step_fn=step_fn, pipe=pipe, start=start, ckpt=mgr)
+
+
+def step_loop(run: TrainRun, steps: Iterable[int], *,
+              before: Optional[Callable[[int, Any], None]] = None,
+              after: Optional[Callable[[int, Any, Dict[str, Any], float], None]] = None,
+              ) -> Tuple[List[float], List[float]]:
+    """Run the train step for each step index of ``steps``: batch ``k`` of
+    the pipeline, one call of the step, the loss read to the host.
+
+    Each step is timed from its batch to its loss, inside a ``train_step``
+    telemetry span (attributes ``step``, ``tokens`` and, with compressed
+    gradients, ``grad_wire_bytes``).  ``before(k, state)`` runs ahead of
+    step ``k`` and ``after(k, state, metrics, seconds)`` behind it, both
+    outside the timing.  Returns the per-step losses and wall seconds; the
+    final state is ``run.state``.
+    """
+    attrs: Dict[str, Any] = {"tokens": run.tokens_per_step}
+    pol = run.plan.grad_compression()
+    if pol is not None and run.plan.mesh is not None:
+        # per-device bytes of one compressed DP reduction
+        n = sum(int(leaf.size) for leaf in jax.tree.leaves(run.state["params"]))
+        attrs["grad_wire_bytes"] = gradc.collective_bytes(n, run.plan.dp, pol)["compressed_total"]
+    losses: List[float] = []
+    seconds: List[float] = []
+    for k in steps:
+        if before is not None:
+            before(k, run.state)
         t0 = time.perf_counter()
-        seconds.append(dt)
+        with telemetry.span("train_step", step=k, **attrs):
+            batch = {name: jnp.asarray(v) for name, v in run.pipe.batch_at(k).items()}
+            run.state, metrics = run.step_fn(run.state, batch)
+            losses.append(float(metrics["loss"]))
+        seconds.append(time.perf_counter() - t0)
+        if after is not None:
+            after(k, run.state, metrics, seconds[-1])
+    return losses, seconds
+
+
+def main(argv=None):
+    run = build(argv, checkpoints=True)
+    args, cfg, mgr = run.args, run.cfg, run.ckpt
+    print(f"arch={cfg.name} family={cfg.family} ~{cfg.n_flop_params()/1e6:.0f}M params")
+    mon = HeartbeatMonitor(["host0"], timeout_s=600)
+
+    def after(k, state, metrics, dt):
         mon.beat("host0", dt)
         if k % 5 == 0 or k == args.steps - 1:
-            print(f"step {k:4d} loss={losses[-1]:.4f} "
-                  f"({args.batch * args.seq / dt:,.0f} tok/s)")
+            print(f"step {k:4d} loss={float(metrics['loss']):.4f} "
+                  f"({run.tokens_per_step / dt:,.0f} tok/s)")
         if (k + 1) % args.ckpt_every == 0:
             mgr.save(k + 1, state, extra={"next_step": k + 1})
+
+    losses, seconds = step_loop(run, range(run.start, args.steps), after=after)
     mgr.wait()
     print("done; checkpoints:", mgr.list_steps())
-    return {"state": state, "losses": losses, "step_seconds": seconds,
+    return {"state": run.state, "losses": losses, "step_seconds": seconds,
             "ckpt": mgr, "cfg": cfg}
 
 

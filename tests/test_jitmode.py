@@ -155,8 +155,11 @@ if HAVE_HYPOTHESIS:
     _arrays = hnp.arrays(
         np.float32,
         st.integers(1, 3000),
+        # the bounds must be exact float32 values: Hypothesis refuses a
+        # width-32 strategy whose bound (1e30) float32 cannot represent
         elements=st.floats(
-            -1e30, 1e30, width=32, allow_nan=False, allow_infinity=False
+            float(np.float32(-1e30)), float(np.float32(1e30)), width=32,
+            allow_nan=False, allow_infinity=False,
         ),
     )
 
