@@ -382,6 +382,35 @@ class ZeroPredictor(Predictor):
 # Dual-quantization Lorenzo (parallel; the TPU-native default)
 # ---------------------------------------------------------------------------
 
+def _breaks_bound(x64, q, recon_dev, quantizer: QuantizerBase) -> np.ndarray:
+    """Where either decode route's reconstruction of the prequantized ``q``
+    lies more than ``eb`` from ``x64``: the host's (``dequantize_int``,
+    float64) or the decode kernel's (``recon_dev``, float32)."""
+    eb = quantizer.eb
+    fail = np.abs(quantizer.dequantize_int(q).astype(np.float64) - x64) > eb
+    fail |= np.abs(recon_dev.astype(np.float64) - x64) > eb
+    return fail
+
+
+def lorenzo_device_fail(
+    data: np.ndarray, draw: np.ndarray, quantizer: QuantizerBase
+) -> np.ndarray:
+    """The full host bound check of the device Lorenzo route.
+
+    ``draw`` is the encode kernel's raw differences; both decode routes are
+    run in full (``lorenzo_inverse``, and the decode kernel's exact int32
+    prefix sums and float32 dequantization).  The device route certifies
+    the check on the chip and re-checks only the points it flags; this is
+    its fallback when too many are flagged, and the oracle its tests
+    compare with.
+    """
+    from ..kernels.lorenzo import ops as lops
+
+    q = lorenzo_inverse(draw.astype(np.int64), 1)
+    recon_dev = lops.decode_pipeline(draw, eb=quantizer.eb)
+    return _breaks_bound(np.asarray(data, np.float64), q, recon_dev, quantizer)
+
+
 class LorenzoPredictor(Predictor):
     """Parallel N-D Lorenzo via dual-quantization (DESIGN.md §3.1).
 
@@ -392,11 +421,13 @@ class LorenzoPredictor(Predictor):
       * device — the fused Pallas prequant+Lorenzo kernels
         (``kernels/lorenzo``), for order-1 float32 1-D/2-D data whose
         prequantized magnitudes pass the ``PIPELINE_SAFE`` int32 guard.
-        The kernel computes q in float32, so after encoding, reconstruction
-        is re-derived EXACTLY as both decode routes will compute it and any
-        bound-breaking point is patched into the fail channel — the error
-        bound is therefore identical to the numpy route's.  ``device="auto"``
-        engages on real TPUs only (interpret-mode Pallas on CPU is far
+        The kernel computes q in float32, so the chip then flags every
+        point whose reconstruction under either decode route can come near
+        the bound; the host re-derives those EXACTLY as both decode routes
+        will compute them and patches any bound-breaking point into the
+        fail channel (:func:`lorenzo_device_fail` when too many are
+        flagged) — the error bound is therefore identical to the numpy
+        route's.  ``device="auto"`` engages on real TPUs only (interpret-mode Pallas on CPU is far
         slower than numpy); ``"force"`` engages everywhere (tests);
         ``"off"`` never.
     """
@@ -442,28 +473,35 @@ class LorenzoPredictor(Predictor):
 
         eb = quantizer.eb
         with tel.span("device_transfer", bytes=data.nbytes):
-            codes32, draw = lops.encode_pipeline(data, eb=eb, radius=quantizer.radius)
+            enc = lops.encode_checked(
+                data, eb=eb, radius=quantizer.radius, code_dtype=quantizer.code_dtype
+            )
+        tel.count("verify_device")
         # The kernel prequantizes in float32 (vs float64 on the numpy route);
-        # verify the bound against BOTH decode routes' exact arithmetic and
-        # divert any straggler through the fail channel (raw values).
+        # the chip flagged every point that can break the bound under either
+        # decode route's arithmetic, and the host re-checks those exactly.
+        # Stragglers ride the fail channel (raw values).
         with tel.span("verify", bytes=data.nbytes):
-            d = draw.astype(np.int64)
-            x64 = np.asarray(data, np.float64)
-            q = lorenzo_inverse(d, 1)
-            recon_np = quantizer.dequantize_int(q)
-            fail = np.abs(recon_np.astype(np.float64) - x64) > eb
-            recon_dev = lops.decode_pipeline(draw, eb=eb)
-            fail |= np.abs(recon_dev.astype(np.float64) - x64) > eb
-        flat = d.reshape(-1)
-        oor = np.abs(flat) >= quantizer.radius
-        if oor.any():
-            quantizer._store_unpred_int(flat[oor])
-        codes = codes32.reshape(-1).astype(quantizer.code_dtype)
+            if enc.overflow:
+                tel.count("verify_host_fallback")
+                draw = np.asarray(enc.draw).reshape(data.shape)
+                fail = lorenzo_device_fail(data, draw, quantizer).reshape(-1)
+                flat = draw.reshape(-1)
+                unpred = flat[np.abs(flat) >= quantizer.radius]
+            else:
+                tel.count("verify_rechecked", enc.n)
+                x64 = data.reshape(-1)[enc.idx].astype(np.float64)
+                bad = _breaks_bound(x64, enc.q, enc.recon, quantizer)
+                fail = np.zeros(data.size, bool)
+                fail[enc.idx[bad]] = True
+                unpred = enc.diffs[enc.codes.reshape(-1)[enc.idx] == 0]
+        if unpred.size:
+            quantizer._store_unpred_int(unpred)
         meta: Dict[str, Any] = {"order": 1, "nfail": int(fail.sum()), "device": 1}
         if meta["nfail"]:
             meta["fail_mask"] = _pack_mask(fail)
-            meta["fail_vals"] = x64[fail].tobytes()
-        return codes, meta
+            meta["fail_vals"] = data.reshape(-1)[fail].astype(np.float64).tobytes()
+        return enc.codes.reshape(-1), meta
 
     def _decode_device_ok(self, shape, dtype, eb: float) -> bool:
         if self.device == "off" or len(shape) not in (1, 2):

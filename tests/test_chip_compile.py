@@ -82,6 +82,19 @@ def test_lorenzo_compiles(one_chip, shape, mode):
     _pallas_text(dec)
 
 
+# a CESM-ATM field, and one of the six row chunks the auto contest cuts it in
+@pytest.mark.parametrize("shape", [(1800, 3600), (300, 3600)])
+def test_lorenzo_check_compiles(one_chip, shape):
+    x = _sds(shape, jnp.float32, one_chip)
+    d = _sds(shape, jnp.int32, one_chip)
+    compiled = lops.lorenzo_check.lower(
+        x, d, d, eb=1e-3, mode="2d", code_dtype=np.dtype(np.uint16)
+    ).compile()
+    codes, n, cand = compiled.out_info
+    assert codes.shape == shape and np.dtype(codes.dtype) == np.uint16
+    assert n.shape == () and cand.shape == (4, lops.CHECK_CAPACITY)
+
+
 @pytest.mark.parametrize("shape", [(1, 1 << 20), (1800, 3600)])
 @pytest.mark.parametrize("mode", ["1d", "2d"])
 def test_transform_compiles(one_chip, shape, mode):
