@@ -1,5 +1,5 @@
 """Benchmark registry — one entry per paper table/figure + the framework
-integration benches + the roofline reader.  Prints ``name,us_per_call,
+integration benches.  Prints ``name,us_per_call,
 derived`` CSV lines per the harness contract; detailed per-bench output goes
 to stdout above each summary line.
 
@@ -52,7 +52,6 @@ def main() -> None:
         bench_pipelines,
         bench_sustainability,
         bench_throughput,
-        roofline,
     )
 
     benches = {
@@ -63,7 +62,6 @@ def main() -> None:
         "chunked_streaming": bench_chunked.main,  # chunked engine vs one-shot
         "sustainability_s6_1": bench_sustainability.main,  # paper §6.1/Table 2
         "integrations": bench_integrations.main,  # beyond-paper (grad/kv/opt/ckpt)
-        "roofline": roofline.main,  # deliverable (g)
     }
     print("name,us_per_call,derived")
     results = []

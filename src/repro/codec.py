@@ -29,7 +29,7 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
-from .core import CompressionConfig, ErrorBoundMode
+from .core import PIPELINES, CompressionConfig, ErrorBoundMode
 from .core import pipeline as pl_mod
 from .core.pipeline import decompress as sz3_decompress
 
@@ -43,7 +43,7 @@ except Exception:  # pragma: no cover - exercised where numcodecs is absent
     _register_codec = None
     _HAVE_NUMCODECS = False
 
-#: friendly predictor aliases -> registered pipeline factory names (the full
+#: friendly predictor aliases -> engine-table names (the full
 #: ``sz3_*`` names are accepted verbatim as well)
 _PREDICTOR_ALIASES = {
     "auto": "sz3_auto",
@@ -73,8 +73,8 @@ class Sz3Codec(_CodecBase):
         The bound numbers for the selected mode.
     predictor:
         Engine name: an alias from ``auto / fast / chunked / hybrid /
-        lorenzo / lr / interp / transform / pwr`` or any registered
-        ``sz3_*`` pipeline name.
+        lorenzo / lr / interp / transform / pwr`` or any ``sz3_*`` name of
+        ``repro.core.PIPELINES``.
     """
 
     codec_id = "repro.sz3"
@@ -92,11 +92,11 @@ class Sz3Codec(_CodecBase):
                 f"eb_mode must be one of {_EB_MODES}, got {eb_mode!r}"
             )
         pname = _PREDICTOR_ALIASES.get(predictor, predictor)
-        if pname not in pl_mod.PIPELINES:
+        if pname not in PIPELINES:
             raise ValueError(
                 f"unknown predictor {predictor!r} (aliases: "
                 f"{sorted(_PREDICTOR_ALIASES)}; registered pipelines: "
-                f"{sorted(pl_mod.PIPELINES)})"
+                f"{sorted(PIPELINES)})"
             )
         if eb_mode in ("abs-and-rel", "abs-or-rel") and eb_rel is None:
             raise ValueError(f"eb_mode {eb_mode!r} needs eb_rel as well")
@@ -137,7 +137,7 @@ class Sz3Codec(_CodecBase):
                     else {"candidates": (self._pname,)}
                 ),
             )
-        factory = pl_mod.PIPELINES[self._pname]
+        factory = PIPELINES[self._pname]
         if self._pname == "sz3_pwr":
             return factory(eb=self.eb_rel if self.eb_rel is not None else self.eb_abs)
         return factory()

@@ -4,8 +4,7 @@ The paper's five-module abstraction (preprocessor -> predictor -> quantizer ->
 encoder -> lossless) composed per §3.3, plus the customized pipelines of §4
 (GAMESS / SZ3-Pastri), §5 (APS adaptive) and §6.2 (LR / Interp / Truncation).
 """
-from . import telemetry  # noqa: I001  (stdlib-only; must import first so
-# every other core module can use it without cycles)
+from . import telemetry
 from .telemetry import Trace, explain, trace_summary
 from . import encoders, lossless, metrics, predictors, preprocess, quantizers
 from . import faults, integrity
@@ -17,8 +16,7 @@ from .integrity import (
     SalvageReport,
     verify_blob,
 )
-from .pipeline import (  # noqa: I001  (chunking must import after pipeline)
-    PIPELINES,
+from .pipeline import (
     AdaptiveAPSCompressor,
     CompressionResult,
     SZ3Compressor,
@@ -50,32 +48,18 @@ from .chunking import (
     sz3_pwr,
     write_frames,
 )
-from . import transform
-from . import blockwise  # noqa: I001  (blockwise must import after transform:
-# it registers sz3_hybrid and appends it to transform.AUTO_CANDIDATES)
-from . import fastmode  # noqa: I001  (fastmode must import after transform:
-# it registers sz3_fast and appends it to transform.AUTO_CANDIDATES)
-from .fastmode import (
-    FastModeCompressor,
-    sz3_fast,
-)
-from .transform import (  # noqa: I001  (re-export AFTER blockwise extends it)
-    AUTO_CANDIDATES,
-    TransformCompressor,
-    sz3_auto,
-    sz3_transform,
-)
-from .blockwise import (
-    BlockHybridCompressor,
-    sz3_hybrid,
-)
-from . import quality
-from .quality import (  # noqa: I001  (quality must import after transform)
+from . import blockwise, fastmode, quality, transform
+from .blockwise import BlockHybridCompressor, sz3_hybrid
+from .fastmode import FastModeCompressor, sz3_fast
+from .quality import (
     QualityCompressor,
     QualityTarget,
     achieved_quality,
     sz3_quality,
 )
+from .transform import TransformCompressor, sz3_transform
+from . import engines
+from .engines import AUTO_CANDIDATES, PIPELINES, sz3_auto
 
 __all__ = [
     "telemetry",
@@ -126,6 +110,7 @@ __all__ = [
     "FastModeCompressor",
     "sz3_fast",
     "fastmode",
+    "engines",
     "compress_stream",
     "decompress_stream",
     "decompress_chunk",

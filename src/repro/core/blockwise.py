@@ -50,7 +50,6 @@ from . import predictors as pred_mod
 from . import preprocess as pre_mod
 from . import quantizers as quant_mod
 from . import telemetry as tel
-from . import transform as tr_mod
 from .config import CompressionConfig, ErrorBoundMode
 from .integrity import ContainerError, guard_alloc, guard_count, guard_shape
 from .pipeline import CompressionResult, container_body, pack_container
@@ -506,11 +505,3 @@ class BlockHybridCompressor:
 def sz3_hybrid(block_side: Optional[int] = None, **kw) -> BlockHybridCompressor:
     """Named factory: block-level multi-predictor hybrid engine (v5)."""
     return BlockHybridCompressor(block_side=block_side, **kw)
-
-
-# registration (blockwise imports pipeline/transform, never vice versa); the
-# hybrid engine also joins the auto contest — sz3_auto / sz3_quality resolve
-# AUTO_CANDIDATES at call time, so they pick this up
-pl_mod.PIPELINES["sz3_hybrid"] = sz3_hybrid
-if "sz3_hybrid" not in tr_mod.AUTO_CANDIDATES:
-    tr_mod.AUTO_CANDIDATES = tr_mod.AUTO_CANDIDATES + ("sz3_hybrid",)

@@ -237,11 +237,13 @@ def _sample_block(
 # ---------------------------------------------------------------------------
 
 def _make_pipeline(name: str):
+    from .engines import PIPELINES  # the table sits above this module
+
     try:
-        factory = pl_mod.PIPELINES[name]
+        factory = PIPELINES[name]
     except KeyError:
         raise KeyError(
-            f"unknown pipeline {name!r}; have {sorted(pl_mod.PIPELINES)}"
+            f"unknown pipeline {name!r}; have {sorted(PIPELINES)}"
         ) from None
     return factory()
 
@@ -249,9 +251,9 @@ def _make_pipeline(name: str):
 #: go to a trial-compression runoff on the sample
 RUNOFF_MARGIN = 1.3
 
-#: nominal compress throughput per pipeline (MB/s, bench box, BENCH_PR5/PR6
-#: order of magnitude) — the ``speed_tier="throughput"`` cost model's price
-#: list.  Only RATIOS between entries matter, so the table survives machine
+#: nominal compress throughput per pipeline (MB/s on a CPU host, order of
+#: magnitude) — the ``speed_tier="throughput"`` cost model's price list.
+#: Only RATIOS between entries matter, so the table survives machine
 #: differences; unknown candidates get the conservative default.
 PIPELINE_MBPS = {
     "sz3_fast": 200.0,
@@ -1035,7 +1037,7 @@ def sz3_chunked(
     workers: int = 1,
     **kw,
 ) -> ChunkedCompressor:
-    """Named factory, registered alongside the paper pipelines."""
+    """Named factory for the chunked engine."""
     return ChunkedCompressor(
         candidates=candidates, chunk_bytes=chunk_bytes, workers=workers, **kw
     )
@@ -1105,9 +1107,3 @@ def sz3_pwr(
         or CompressionConfig(mode=ErrorBoundMode.PW_REL, eb=eb),
         **kw,
     )
-
-
-# register with the named-pipeline table (PIPELINES lives in pipeline.py;
-# chunking imports pipeline, so registration happens here to avoid a cycle)
-pl_mod.PIPELINES["sz3_chunked"] = sz3_chunked
-pl_mod.PIPELINES["sz3_pwr"] = sz3_pwr

@@ -1,10 +1,10 @@
 """SZx-style ultra-fast fixed-length coder (v6 container, factory ``sz3_fast``).
 
 The prediction pipelines buy ratio with an entropy stage (Huffman + lossless)
-whose encode cost dominates end-to-end throughput (BENCH_PR5: ~18-22 MB/s
-chunked compress).  SZx ("An Ultra-fast Error-bounded Lossy Compressor",
-PAPERS.md) shows the other end of the speed-ratio frontier: fixed-length
-coding with NO entropy pass at all.  This module is that tier.
+whose encode cost dominates end-to-end throughput.  SZx ("An Ultra-fast
+Error-bounded Lossy Compressor", PAPERS.md) shows the other end of the
+speed-ratio frontier: fixed-length coding with NO entropy pass at all.  This
+module is that tier.
 
 Format (all offsets derivable from the header — no in-band markers):
 
@@ -60,7 +60,6 @@ from . import lossless as ll_mod
 from . import pipeline as pl_mod
 from . import preprocess as pre_mod
 from . import telemetry as tel
-from . import transform as tr_mod
 from .config import CompressionConfig, ErrorBoundMode
 from .integrity import ContainerError, guard_alloc, guard_count, guard_shape
 from .pipeline import CompressionResult, container_body, pack_container
@@ -574,11 +573,3 @@ def sz3_fast(
     return FastModeCompressor(
         bs=bs, lossless=ll_mod.make(lossless), device=device, **kw
     )
-
-
-# registration (fastmode imports pipeline/transform, never vice versa); the
-# fast tier also joins the auto contest — sz3_auto / sz3_quality resolve
-# AUTO_CANDIDATES at call time, so they pick this up
-pl_mod.PIPELINES["sz3_fast"] = sz3_fast
-if "sz3_fast" not in tr_mod.AUTO_CANDIDATES:
-    tr_mod.AUTO_CANDIDATES = tr_mod.AUTO_CANDIDATES + ("sz3_fast",)

@@ -61,8 +61,8 @@ reference ``encode_host`` produce bit-identical codes — pinned by
 
 Host fallback: anything outside a jit region that wants the *prediction*
 engines (entropy-coded containers, integrity trailers, random access) should
-route through the registry — :func:`host_compress` / :func:`host_decompress`
-are the facade's thin door to ``pipeline.PIPELINES`` for exactly that
+route through the engine table — :func:`host_compress` / :func:`host_decompress`
+are the facade's thin door to ``engines.PIPELINES`` for exactly that
 (``ft/checkpoint.py`` is the house consumer).
 """
 from __future__ import annotations
@@ -598,20 +598,19 @@ def decode_host(c: BlockCodes) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# host fallback: the registered prediction engines
+# host fallback: the prediction engines of the engine table
 # ---------------------------------------------------------------------------
 
 def host_compress(arr: np.ndarray, engine: str = "sz3_auto", conf=None):
-    """Route a host-side array through a REGISTERED pipeline (the facade's
+    """Route a host-side array through a named pipeline (the facade's
     door to the entropy-coded engines for non-jit contexts)."""
-    from . import pipeline as pl_mod
-    from .transform import sz3_auto  # noqa: F401 (registers sz3_auto)
+    from .engines import PIPELINES
 
-    if engine not in pl_mod.PIPELINES:
+    if engine not in PIPELINES:
         raise KeyError(
-            f"unknown engine {engine!r}; registered: {sorted(pl_mod.PIPELINES)}"
+            f"unknown engine {engine!r}; registered: {sorted(PIPELINES)}"
         )
-    comp = pl_mod.PIPELINES[engine]()
+    comp = PIPELINES[engine]()
     return comp.compress(np.asarray(arr), conf)
 
 

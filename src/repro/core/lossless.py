@@ -149,7 +149,7 @@ class Zstd(LosslessBackend):
         except _zstd.ZstdError as e:
             if "output" in str(e).lower():
                 raise _bomb(max_out, "zstd") from e
-            raise
+            raise ContainerError(f"corrupt zstd stream: {e}") from e
 
 
 class Gzip(LosslessBackend):

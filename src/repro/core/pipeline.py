@@ -6,7 +6,8 @@ composition is data ("spec"), mirroring SZ3's compile-time template
 polymorphism with trace/construction-time polymorphism (DESIGN.md §4.1).
 
 The container format is self-describing: the header records the module spec,
-so ``decompress(blob)`` rebuilds the exact pipeline.  Named factory pipelines:
+so ``decompress(blob)`` rebuilds the exact pipeline.  Named factory pipelines
+(the name -> factory table, ``PIPELINES``, is in ``engines.py``):
 
   sz3_lr          — composite(Lorenzo+regression) + linear quant + Huffman + zstd   (= SZ2 [8])
   sz3_interp      — interpolation + linear quant + Huffman + zstd                   ([17])
@@ -16,11 +17,11 @@ so ``decompress(blob)`` rebuilds the exact pipeline.  Named factory pipelines:
   sz3_aps         — error-bound-adaptive APS pipeline                               (paper §5)
   sz3_lorenzo     — pure dual-quant Lorenzo (TPU-native fast path)
   sz3_chunked     — streaming chunked engine, per-chunk pipeline selection
-                    (registered by chunking.py; emits the v2 container)
+                    (chunking.py; emits the v2 container)
   sz3_transform   — blockwise decorrelating transform + exponent-aligned
-                    bitplane coding (registered by transform.py; v3 header)
-  sz3_auto        — chunked engine whose candidate set spans BOTH coder
-                    families (prediction + transform; transform.py)
+                    bitplane coding (transform.py; v3 header)
+  sz3_auto        — chunked engine whose candidate set spans every coder
+                    family (engines.py: ``AUTO_CANDIDATES``)
   sz3_pwr         — first-class pointwise-relative engine: log-composed
                     chunk pipelines, v4 container (chunking.py)
   sz3_quality     — closed-loop quality-targeted rate controller
@@ -646,15 +647,3 @@ def sz3_pastri(pattern_size: int = None) -> SZ3Compressor:
 
 def sz3_aps(threshold: float = 0.5, time_axis: int = 0) -> AdaptiveAPSCompressor:
     return AdaptiveAPSCompressor(threshold=threshold, time_axis=time_axis)
-
-
-PIPELINES = {
-    "sz3_lr": sz3_lr,
-    "sz3_interp": sz3_interp,
-    "sz3_lorenzo": sz3_lorenzo,
-    "sz3_truncation": sz3_truncation,
-    "sz_pastri": sz_pastri,
-    "sz_pastri_zstd": sz_pastri_zstd,
-    "sz3_pastri": sz3_pastri,
-    "sz3_aps": sz3_aps,
-}

@@ -55,17 +55,6 @@ from .chunking import (
 from .config import CompressionConfig, ErrorBoundMode
 from .pipeline import CompressionResult
 
-# ensure the block-hybrid engine is registered before the candidate set is
-# read: the quality controller's contest spans ALL families (prediction,
-# transform, block-hybrid)
-from . import blockwise as _blockwise  # noqa: F401
-from . import transform as _transform
-
-
-def _auto_candidates() -> Sequence[str]:
-    """Late-bound AUTO_CANDIDATES (blockwise.py extends it at import time)."""
-    return _transform.AUTO_CANDIDATES
-
 #: chunk-MSE aim band as a fraction of the per-chunk MSE budget: the upper
 #: edge is the hard budget (never exceeded after confirmation), the lower
 #: edge stops the bisection from over-spending bits on needless accuracy
@@ -167,9 +156,11 @@ class QualityCompressor:
         conf: Optional[CompressionConfig] = None,
         workers: int = 1,
     ):
+        from .engines import AUTO_CANDIDATES  # the table sits above this module
+
         self.target = QualityTarget(target_psnr, target_ratio, target_bitrate)
         self.candidates = tuple(
-            _auto_candidates() if candidates is None else candidates
+            AUTO_CANDIDATES if candidates is None else candidates
         )
         self.chunk_bytes = int(chunk_bytes)
         self.conf = conf or CompressionConfig()
@@ -535,7 +526,3 @@ def sz3_quality(
         workers=workers,
         **kw,
     )
-
-
-# registration (quality imports pipeline/chunking/transform, never vice versa)
-pl_mod.PIPELINES["sz3_quality"] = sz3_quality

@@ -44,7 +44,6 @@ import numpy as np
 from . import lossless as ll_mod
 from . import pipeline as pl_mod
 from . import telemetry as tel
-from .chunking import DEFAULT_CANDIDATES, ChunkedCompressor
 from .config import CompressionConfig, ErrorBoundMode
 from .integrity import ContainerError, guard_alloc, guard_count, guard_shape
 from .pipeline import CompressionResult, container_body, pack_container
@@ -418,37 +417,9 @@ def _decode_device_ok(pshape: Tuple[int, ...]) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# named pipelines: the transform family + the hybrid auto candidate set
+# named pipeline
 # ---------------------------------------------------------------------------
 
 def sz3_transform(lossless: str = "zstd", device: str = "auto") -> TransformCompressor:
     """Pure transform coder (ZFP-family analogue)."""
     return TransformCompressor(lossless=lossless, device=device)
-
-
-#: prediction AND transform entrants — the online SZ/ZFP selection criterion.
-#: blockwise.py appends "sz3_hybrid" at import time, so consumers must read
-#: this at CALL time (late binding), never capture it in a default argument.
-AUTO_CANDIDATES: Tuple[str, ...] = DEFAULT_CANDIDATES + ("sz3_transform",)
-
-
-def sz3_auto(
-    candidates=None,
-    chunk_bytes: int = 1 << 22,
-    workers: int = 1,
-    **kw,
-) -> ChunkedCompressor:
-    """Chunked engine contesting prediction vs transform (vs block-hybrid)
-    per chunk.  ``candidates=None`` resolves ``AUTO_CANDIDATES`` at call
-    time so late-registered engines join the contest."""
-    return ChunkedCompressor(
-        candidates=AUTO_CANDIDATES if candidates is None else candidates,
-        chunk_bytes=chunk_bytes,
-        workers=workers,
-        **kw,
-    )
-
-
-# registration happens here (transform imports pipeline, not vice versa)
-pl_mod.PIPELINES["sz3_transform"] = sz3_transform
-pl_mod.PIPELINES["sz3_auto"] = sz3_auto

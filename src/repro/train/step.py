@@ -14,7 +14,7 @@ Two modes:
     duplicates work on CPU test meshes.
 
 State = {params, opt{m,v,step}, feedback?}.  All specs are derived from
-parallel/specs.py so launch/dryrun.py and examples share one source of truth.
+parallel/specs.py so the launchers and examples share one source of truth.
 """
 from __future__ import annotations
 

@@ -290,7 +290,7 @@ def test_strided_probes_fix_piecewise_selection_bias():
     sampling must rank it first, while the old single centred probe
     (probes=1) demonstrably picks a smooth-regime pipeline instead."""
     from repro.core.chunking import _sample_block
-    from repro.core.transform import AUTO_CANDIDATES
+    from repro.core import AUTO_CANDIDATES
 
     x = _piecewise_chunk()
     conf = CompressionConfig(mode=ErrorBoundMode.ABS, eb=1e-3)

@@ -209,7 +209,7 @@ def test_invalid_speed_tier_rejected():
 
 
 def test_fast_registered_everywhere():
-    from repro.core.transform import AUTO_CANDIDATES
+    from repro.core import AUTO_CANDIDATES
 
     assert "sz3_fast" in PIPELINES
     assert "sz3_fast" in AUTO_CANDIDATES
