@@ -342,6 +342,7 @@ def _encode_device(arr: np.ndarray) -> Tuple[np.ndarray, np.ndarray, bytes]:
     dense = np.zeros(huffman.TABLE_SIZE, np.uint32)
     dense[vals] = huffman.table_entries(table.enc_code, table.enc_len)
     total_bits = int(np.dot(freqs, lens.astype(np.int64)))
+    tel.tag("huffman", table_rows=int(huffman.table_rows(dense).size))
     sync, payload = huffman.pack_stream(arr, dense, total_bits)
     return vals, lens, _stream_bytes(arr.size, total_bits, sync, payload)
 

@@ -31,7 +31,7 @@ from jax.experimental.pallas import tpu as pltpu
 _SEQ = pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary"))
 
 
-def _prefix_sum(x: jnp.ndarray, axis: int) -> jnp.ndarray:
+def prefix_sum(x: jnp.ndarray, axis: int) -> jnp.ndarray:
     """Inclusive int32 prefix sum along ``axis`` (log-step scan)."""
     n = x.shape[axis]
     idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
@@ -94,7 +94,7 @@ def _decode1d_kernel(d_ref, out_ref, col_ref, *, two_eb):
     def _():
         col_ref[...] = jnp.zeros_like(col_ref)
 
-    q = _prefix_sum(d_ref[...], 1) + col_ref[...]
+    q = prefix_sum(d_ref[...], 1) + col_ref[...]
     col_ref[...] = q[:, -1:]
     out_ref[...] = q.astype(jnp.float32) * two_eb
 
@@ -110,9 +110,9 @@ def _decode2d_kernel(d_ref, out_ref, row_ref, col_ref, *, two_eb):
     def _():
         col_ref[...] = jnp.zeros_like(col_ref)
 
-    r = _prefix_sum(d_ref[...], 1) + col_ref[...]  # running row sums
+    r = prefix_sum(d_ref[...], 1) + col_ref[...]  # running row sums
     col_ref[...] = r[:, -1:]
-    q = _prefix_sum(r, 0) + row_ref[j]
+    q = prefix_sum(r, 0) + row_ref[j]
     row_ref[j] = q[-1:, :]
     out_ref[...] = q.astype(jnp.float32) * two_eb
 

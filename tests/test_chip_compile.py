@@ -115,8 +115,10 @@ def test_fastmode_stats_compile(one_chip):
 def test_huffman_pack_compiles(one_chip, size, log2_words):
     syms = _sds((size,), jnp.uint16, one_chip)
     table = _sds((huffman.TABLE_SIZE,), jnp.uint32, one_chip)
-    n = _sds((), jnp.int32, one_chip)
-    compiled = huffman.huffman_pack.lower(syms, table, n, n_words=1 << log2_words).compile()
+    meta = _sds((2,), jnp.int32, one_chip)
+    rows = _sds((huffman.TABLE_SIZE // 128,), jnp.int32, one_chip)
+    compiled = huffman.huffman_pack.lower(syms, table, meta, rows, n_words=1 << log2_words).compile()
+    _pallas_text(compiled)
     sync, pieces = compiled.out_info
     assert sync.shape == (size // 1024,)
     assert sum(p.shape[0] for p in pieces) == 1 << log2_words

@@ -11,7 +11,7 @@ import pytest
 from repro.core import CompressionConfig, ErrorBoundMode, decompress, encoders
 from repro.core import telemetry as tel
 from repro.core.pipeline import sz3_lorenzo
-from repro.kernels import routing
+from repro.kernels import huffman, routing
 
 FLOOR = encoders._DEVICE_MIN_CODES
 #: the floor the byte-identity tests pack at, to keep them small on the CPU
@@ -72,6 +72,57 @@ def _words_fill_their_bucket(rng):
     return np.full(SMALL, 7, np.uint16)
 
 
+#: bits of a tile, the unit in which the kernel's staged words go out
+TILE_BITS = 32 * huffman.TILE_WORDS
+
+
+def _zero_and_65535(rng):
+    # the alphabet's ends and values in between: the lookup scans most rows
+    vals = np.r_[0, 65535, rng.integers(1, 65535, 400)]
+    return rng.choice(vals, SMALL + 999).astype(np.uint16)
+
+
+def _one_bit_runs_cross_tiles(rng):
+    # two equally frequent symbols: one-bit codes, 32 a word, past three tiles
+    return rng.permutation(np.repeat([40_000, 40_001], (3 * TILE_BITS + 4_000) // 2)).astype(np.uint16)
+
+
+def _tails_into_next_tile(rng):
+    # geometric frequencies give fifteen 16-bit codes; each is put 8 bits
+    # before a tile's end, so its tail is the next tile's first word
+    counts = 2 ** np.arange(17, -1, -1)
+    lens, _ = encoders._huffman_code_lengths(counts)
+    syms = 500 + np.arange(counts.size)
+    long = np.repeat(syms[lens == 16], counts[lens == 16])
+    one = int(syms[lens == 1][0])
+    reserve = 2_000  # one-bit codes that align the long ones
+    rest = np.repeat(syms[lens < 16], counts[lens < 16] - np.where(syms[lens < 16] == one, reserve, 0))
+    width = dict(zip(syms.tolist(), lens.tolist()))
+    out, pos, k = [], 0, 0
+    for s in rng.permutation(rest).tolist():
+        mark = (k + 1) * TILE_BITS - 8
+        if k < long.size and pos + width[s] > mark:
+            out += [one] * (mark - pos)
+            reserve -= mark - pos
+            out.append(long[k])
+            pos, k = mark + 16, k + 1
+        out.append(s)
+        pos += width[s]
+    assert k == long.size and reserve >= 0
+    return np.asarray(out + [one] * reserve, np.uint16)
+
+
+def _ends_one_code_past_a_tile(rng):
+    # 256 symbols of 8 bits: 17 whole tiles, then one code
+    n = 17 * TILE_BITS // 8 + 1
+    return rng.permutation(np.arange(n) % 256 + 1024).astype(np.uint16)
+
+
+def _fills_one_tile(rng):
+    # four symbols of 2 bits: exactly one tile (below SMALL codes)
+    return rng.permutation(np.arange(TILE_BITS // 2) % 4 + 9).astype(np.uint16)
+
+
 def _uint32_codes(rng):
     return _symbols_23(rng).astype(np.uint32)
 
@@ -88,6 +139,11 @@ CASES = {
     "n_not_multiple_of_1024": _n_not_multiple_of_sync,
     "total_bits_on_word_boundary": _word_boundary,
     "words_fill_their_bucket": _words_fill_their_bucket,
+    "zero_and_65535": _zero_and_65535,
+    "one_bit_runs_cross_tiles": _one_bit_runs_cross_tiles,
+    "16_bit_tails_into_next_tile": _tails_into_next_tile,
+    "ends_one_code_past_a_tile": _ends_one_code_past_a_tile,
+    "fills_exactly_one_tile": _fills_one_tile,
     "uint32_codes": _uint32_codes,
     "int64_codes": _int64_codes,
 }
@@ -102,9 +158,16 @@ def _stream_head(blob):
     return k, lens, int(head[1]), int(head[2])
 
 
+def _code_offsets(codes, vals, lens):
+    """Each code's length and bit offset in the stream."""
+    width = lens[np.searchsorted(vals, codes)].astype(np.int64)
+    return width, np.cumsum(width) - width
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_device_stream_byte_identical(route, small_floor, case):
+def test_device_stream_byte_identical(route, small_floor, monkeypatch, case):
     codes = CASES[case](np.random.default_rng(len(case)))
+    monkeypatch.setattr(encoders, "_DEVICE_MIN_CODES", min(SMALL, codes.size))
     enc = encoders.HuffmanEncoder()
     route(False)
     host = enc.encode(codes)
@@ -129,6 +192,21 @@ def test_device_stream_byte_identical(route, small_floor, case):
         assert total_bits % 32 == 0
     elif case == "words_fill_their_bucket":
         assert total_bits == 32 * 2048
+    elif case == "zero_and_65535":
+        vals = np.frombuffer(dev, np.int64, count=k, offset=8)
+        assert vals[0] == 0 and vals[-1] == 65535
+        assert np.unique(vals >> 7).size > 200
+    elif case == "one_bit_runs_cross_tiles":
+        assert set(lens) == {1} and total_bits > 3 * TILE_BITS
+    elif case == "16_bit_tails_into_next_tile":
+        vals = np.frombuffer(dev, np.int64, count=k, offset=8)
+        width, offs = _code_offsets(codes, vals, lens)
+        spills = (width == 16) & (offs % TILE_BITS == TILE_BITS - 8)
+        assert spills.sum() == 15
+    elif case == "ends_one_code_past_a_tile":
+        assert total_bits == 17 * TILE_BITS + 8
+    elif case == "fills_exactly_one_tile":
+        assert total_bits == TILE_BITS
     assert np.array_equal(enc.decode(dev, codes.size), codes.astype(np.int64))
 
 
@@ -205,3 +283,16 @@ def test_host_route_for_values_beyond_the_device_table(route):
         blob = encoders.HuffmanEncoder().encode(wide)
     assert "huffman_device" not in tr.counters
     assert np.array_equal(encoders.HuffmanEncoder().decode(blob, wide.size), wide)
+
+
+@pytest.mark.parametrize("case", ["one_symbol", "about_300_symbols", "zero_and_65535"])
+def test_table_rows_tag_is_the_occupied_rows(route, small_floor, case):
+    codes = CASES[case](np.random.default_rng(len(case)))
+    route(True)
+    with tel.trace() as tr:
+        with tel.span("huffman") as sp:
+            blob = encoders.HuffmanEncoder().encode(codes)
+    assert tr.counters.get("huffman_device") == 1
+    k = int(np.frombuffer(blob, np.int64, count=1)[0])
+    vals = np.frombuffer(blob, np.int64, count=k, offset=8)
+    assert sp.attrs["table_rows"] == np.unique(vals >> 7).size
